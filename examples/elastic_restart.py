@@ -12,7 +12,7 @@ import sys
 SCRIPT = r"""
 import tempfile, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding
-from repro.launch.mesh import make_test_mesh, use_mesh
+from repro.launch.mesh import make_test_mesh
 from repro.configs import get_config
 from repro.models.sharding import MeshAxes, param_specs
 from repro.train.trainer import TrainConfig, init_train_state, make_train_step
@@ -28,7 +28,7 @@ def run_steps(mesh, state, n, start):
     ns = lambda s: NamedSharding(mesh, s)
     state = jax.device_put(state, jax.tree.map(ns, param_specs(axes, state)))
     step = jax.jit(make_train_step(cfg, tcfg, axes), donate_argnums=0)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(start, start + n):
             state, m = step(state, data.batch_at(i))
             print(f"  mesh={tuple(mesh.shape.values())} step {i} "

@@ -52,12 +52,15 @@ which is precisely why the tree must be VMEM-resident (HBM-blocked
 variants would pay a round-trip per level, reproducing the x86 cache
 line ping-pong the paper fights).
 
-Mosaic-lowering caveat (documented per docs/design.md §6): the round body
-uses one scatter (winner commit) and K-length gathers (arbitration
-reads); these lower on interpret mode (our validation path on this
-CPU-only container) and current Mosaic dynamic-gather support; the
-jnp reference (`core/concurrent.py`, shared verbatim via
-`alloc_round`) is the fallback implementation on any backend.
+Mosaic does not lower these kernels (docs/design.md §6): the round body
+commits winners with a scatter, and compiling any entry point here for
+a TPU v5e fails with Mosaic's `NotImplementedError: Unimplemented
+primitive in Pallas TPU lowering ...: scatter`, in both tree layouts.
+They run only in interpret mode, where the tests check them against
+the XLA round bodies (`core/concurrent.py`, shared verbatim via
+`alloc_round`).  The XLA round bodies are what the serving engine runs
+and what `ops.nbbs_*` resolve `impl="auto"` to on every backend; an
+explicit `impl="pallas"` raises Mosaic's error.
 """
 
 from __future__ import annotations
@@ -447,9 +450,9 @@ def wavefront_alloc_pallas(
 ) -> Tuple[Array, Array, Array, Array]:
     """Pallas entry point. Returns (tree, nodes, ok, stats[3]).
 
-    `interpret=True` is the validation mode on CPU (kernel body executed
-    in Python); on a TPU runtime pass interpret=False to lower via
-    Mosaic.
+    `interpret=True` (the default) runs the kernel body in Python, the
+    only way these kernels run (see the module docstring: Mosaic
+    refuses the scatter in the round body).
     """
     if active is None:
         active = jnp.ones(levels.shape, dtype=jnp.int32)

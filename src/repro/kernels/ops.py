@@ -1,13 +1,16 @@
 """Public kernel ops: backend dispatch + differentiability.
 
 Selection policy (`impl`):
-  "auto"      — Pallas/Mosaic on TPU backends, pure-jnp reference
-                otherwise (XLA CPU/GPU cannot lower Mosaic kernels;
-                the dry-run lowers the reference path — identical math,
-                verified allclose by the kernel test sweeps).
-  "pallas"    — compiled Pallas (TPU runtime).
+  "auto"      — attention: Pallas/Mosaic on TPU backends, pure-jnp
+                reference otherwise (XLA CPU/GPU cannot lower Mosaic
+                kernels; identical math, verified allclose by the kernel
+                test sweeps).  NBBS ops: the XLA round bodies on every
+                backend — Mosaic has no scatter lowering, so the NBBS
+                kernels do not compile for a TPU (see `nbbs_alloc.py`).
+  "pallas"    — compiled Pallas (TPU runtime); a kernel Mosaic refuses
+                raises Mosaic's own error.
   "interpret" — Pallas interpret mode (CPU validation; slow).
-  "reference" — pure-jnp oracle.
+  "reference" — pure-jnp / XLA oracle.
 
 `flash_attention` is differentiable: forward may use the fused kernel,
 backward recomputes through the reference (identical math -> exact
@@ -64,6 +67,12 @@ def default_impl() -> str:
 
 def _resolve(impl: str) -> str:
     return default_impl() if impl == "auto" else impl
+
+
+def _resolve_nbbs(impl: str) -> str:
+    # the NBBS round bodies commit winners with a scatter, which Mosaic
+    # cannot lower: "auto" names the XLA path the engine runs anyway
+    return "reference" if impl == "auto" else impl
 
 
 # ---------------------------------------------------------------------------
@@ -183,7 +192,7 @@ def nbbs_wavefront_alloc(
     impl: str = "auto",
 ):
     """Returns (tree, nodes, ok, stats-dict)."""
-    impl = _resolve(impl)
+    impl = _resolve_nbbs(impl)
     if impl == "reference":
         if active is None:
             active = jnp.ones(levels.shape, dtype=bool)
@@ -215,7 +224,7 @@ def nbbs_wavefront_step(
 ):
     """Mixed release+allocation round (frees via the merged vectorized
     pass, then the alloc wavefront).  Returns (tree, nodes, ok, stats)."""
-    impl = _resolve(impl)
+    impl = _resolve_nbbs(impl)
     if active is None:
         active = jnp.ones(levels.shape, dtype=bool)
     if impl == "reference":
@@ -276,7 +285,7 @@ def nbbs_pool_wavefront_step(
     the driver fills the aggregate 'magazine_*' slots.  Returns
     (trees, mags, nodes, shard, ok, stats) in this mode.
     """
-    impl = _resolve(impl)
+    impl = _resolve_nbbs(impl)
     K = levels.shape[0]
     if active is None:
         active = jnp.ones(levels.shape, dtype=bool)
@@ -356,9 +365,7 @@ def nbbs_pool_wavefront_step(
         fa = jnp.zeros_like(free_active)  # frees apply on the first launch
         # early exit is an eager-mode optimization only: under jit
         # `pending` is a tracer and the loop simply runs all S launches
-        if not isinstance(pending, jax.core.Tracer) and not bool(
-            pending.any()
-        ):
+        if jax.core.is_concrete(pending) and not bool(pending.any()):
             break
     if mags is not None:
         # exhaustion spill-back + retry: one merged release of every

@@ -36,7 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, input_specs, ARCH_NAMES
 from repro.configs.base import ArchConfig, ShapeSpec
-from repro.launch.mesh import dp_axes, make_production_mesh, use_mesh
+from repro.launch.mesh import dp_axes, make_production_mesh
 from repro.models.sharding import MeshAxes, param_specs
 from repro.models.transformer import decode_step, init_cache, init_params, prefill
 from repro.roofline.hlo_analysis import HW_V5E, analyze_hlo, roofline_terms
@@ -234,7 +234,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.size
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered, meta = build_cell(cfg, shape, mesh, multi_pod, variant)
         t_lower = time.time() - t0
         compiled = lowered.compile()

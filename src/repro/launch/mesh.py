@@ -4,41 +4,26 @@ A function (not a module-level constant) so importing never touches JAX
 device state; `dryrun.py` sets the 512-placeholder-device XLA flag
 before its first jax import and then calls this.
 
-Version compatibility: `jax.sharding.AxisType` and `jax.set_mesh` only
-exist from JAX 0.5/0.6 onwards.  On older runtimes (the pinned 0.4.x
-toolchain) `make_mesh` simply omits `axis_types` (explicit-axis meshes
-degrade to the default auto behaviour) and `use_mesh` falls back to the
-classic `with mesh:` resource-env context.  All repo code and test
-snippets must go through these helpers instead of touching the raw JAX
-API.
+Meshes use `AxisType.Auto` axes (`jax.make_mesh` defaults to Explicit):
+the model code places activations with sharding constraints and leaves
+the rest to the SPMD partitioner.  Activate one with `jax.set_mesh`.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """{'axis_types': (Auto,)*n} when the running JAX supports it, else {}."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def use_mesh(mesh):
-    """Context manager activating `mesh` (jax.set_mesh on new JAX, the
-    Mesh resource-env context on 0.4.x)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """(16,16) data x model single pod; (2,16,16) pod x data x model."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return _auto_mesh(shape, axes)
 
 
 def dp_axes(multi_pod: bool) -> tuple:
@@ -47,4 +32,4 @@ def dp_axes(multi_pod: bool) -> tuple:
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU tests (requires forced host device count)."""
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return _auto_mesh(shape, axes)

@@ -96,10 +96,12 @@ def paged_decode_step(
     slot = context_lens % page_tokens
     ctx_att = jnp.where(active, context_lens + 1, 0)
 
-    new_k, new_v = [], []
-
-    def body(x, xs):
-        lp, window, kp, vp = xs  # kp/vp: [P, page, Hkv, D] this layer
+    def body(carry, xs):
+        # the pools ride the carry and are updated in place: passed as
+        # scan xs/ys, XLA keeps a second whole-pool buffer for the
+        # stacked ys, which does not fit beside stablelm-3b on one v5e
+        x, pk, pv = carry  # pk/pv: [L, P, page, Hkv, D]
+        lp, window, layer = xs
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         q = (h @ lp["attn"]["wq"].astype(dtype)).reshape(
             B, 1, cfg.n_heads, cfg.head_dim
@@ -114,12 +116,12 @@ def paged_decode_step(
         k = apply_rope(k, positions, cfg.rope_theta)
         # scatter this token's K/V into its page (inactive lanes were
         # redirected to the OOB page above and are dropped here)
-        kp = kp.at[page_idx, slot].set(k[:, 0], mode="drop")
-        vp = vp.at[page_idx, slot].set(v[:, 0], mode="drop")
+        pk = pk.at[layer, page_idx, slot].set(k[:, 0], mode="drop")
+        pv = pv.at[layer, page_idx, slot].set(v[:, 0], mode="drop")
         o = ops.paged_attention(
             q[:, 0],
-            kp,
-            vp,
+            pk[layer],
+            pv[layer],
             block_tables,
             ctx_att,
             softcap=cfg.attn_softcap or None,
@@ -143,10 +145,11 @@ def paged_decode_step(
             h = apply_swiglu(lp["mlp"], h, dtype=dtype)
         if cfg.post_norm:
             h = rms_norm(h, lp["ln2_post"], cfg.norm_eps)
-        return x + h, (kp, vp)
+        return (x + h, pk, pv), None
 
-    x, (ks, vs) = jax.lax.scan(
-        body, x, (params["layers"], windows, pool["k"], pool["v"])
+    layers = jnp.arange(cfg.n_layers)
+    (x, ks, vs), _ = jax.lax.scan(
+        body, (x, pool["k"], pool["v"]), (params["layers"], windows, layers)
     )
     h = rms_norm(x, params["final_norm"], cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["lm_head"]

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 Array = jax.Array
 
@@ -81,15 +80,10 @@ def pipeline_apply(
             return (buf, nxt), None
 
         # initial carry must be marked varying over the pipe axis (each
-        # stage's carry evolves independently between ppermutes); JAX
-        # before 0.5 has no varying-type system (no lax.pcast) and needs
-        # no marking.
-        pcast = getattr(lax, "pcast", None)
-        mark_varying = (
-            (lambda a: pcast(a, (axis,), to="varying")) if pcast else (lambda a: a)
-        )
+        # stage's carry evolves independently between ppermutes)
         init = jax.tree.map(
-            mark_varying, (buf, jnp.zeros(mb_shape, xs.dtype))
+            lambda a: lax.pcast(a, (axis,), to="varying"),
+            (buf, jnp.zeros(mb_shape, xs.dtype)),
         )
         (buf, _), _ = lax.scan(step, init, jnp.arange(steps))
         # broadcast the last stage's outputs to all stages (masked psum:
@@ -100,7 +94,7 @@ def pipeline_apply(
         return out
 
     pspec_params = jax.tree.map(lambda _: P(axis), stacked_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(pspec_params, P()),

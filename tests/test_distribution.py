@@ -69,14 +69,13 @@ def test_compressed_psum_on_mesh():
     out = run_with_devices(4, """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.launch.mesh import make_test_mesh
 from repro.optim.compression import compressed_psum
 
 mesh = make_test_mesh((4,), ("dp",))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 512))
 
-f = shard_map(
+f = jax.shard_map(
     lambda g: compressed_psum(g[0], "dp"),
     mesh=mesh, in_specs=P("dp", None), out_specs=P(),
 )
@@ -94,7 +93,7 @@ def test_sharded_train_step_and_elastic_restore():
     out = run_with_devices(8, """
 import tempfile, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import make_test_mesh, use_mesh
+from repro.launch.mesh import make_test_mesh
 from repro.configs import get_config
 from repro.models.sharding import MeshAxes, param_specs
 from repro.train.trainer import TrainConfig, init_train_state, make_train_step
@@ -111,7 +110,7 @@ def steps_on_mesh(mesh, state, n, start):
     specs = param_specs(axes, state)
     state = jax.device_put(state, jax.tree.map(ns, specs))
     step = jax.jit(make_train_step(cfg, tcfg, axes), donate_argnums=0)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(start, start + n):
             state, m = step(state, data.batch_at(i))
     return state, float(m["loss"])
@@ -140,7 +139,7 @@ def test_single_device_vs_sharded_same_loss():
     out = run_with_devices(4, """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding
-from repro.launch.mesh import make_test_mesh, use_mesh
+from repro.launch.mesh import make_test_mesh
 from repro.configs import get_config
 from repro.models.sharding import MeshAxes, param_specs
 from repro.models import init_params
@@ -157,7 +156,7 @@ mesh = make_test_mesh((2, 2), ("data", "model"))
 axes = MeshAxes(dp=("data",), tp="model", fsdp=True)
 ns = lambda s: NamedSharding(mesh, s)
 p_sh = jax.device_put(params, jax.tree.map(ns, param_specs(axes, params)))
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     l_shard = float(jax.jit(
         lambda p, b: train_loss(cfg, p, b, axes=axes, dtype=jnp.float32,
                                 remat=False)
@@ -175,7 +174,7 @@ def test_dryrun_cell_builder_on_small_mesh():
     out = run_with_devices(8, """
 import jax
 from jax.sharding import Mesh
-from repro.launch.mesh import make_test_mesh, use_mesh
+from repro.launch.mesh import make_test_mesh
 from repro.launch import dryrun
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
@@ -185,7 +184,7 @@ mesh = make_test_mesh((4, 2), ("data", "model"))
 for spec in (ShapeSpec("t", 32, 8, "train"),
              ShapeSpec("p", 32, 8, "prefill"),
              ShapeSpec("d", 32, 8, "decode")):
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered, meta = dryrun.build_cell(cfg, spec, mesh, False)
         compiled = lowered.compile()
         assert compiled.cost_analysis() is not None

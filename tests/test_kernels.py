@@ -16,6 +16,7 @@ from repro.core.concurrent import (
 
 _LAYOUTS = {"unpacked": UNPACKED, "packed": BUNCH_PACKED}
 from repro.core.pool import PoolConfig
+from repro.kernels import ops as ops_mod
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.nbbs_alloc import wavefront_alloc_pallas, wavefront_step_pallas
 from repro.kernels.ops import (
@@ -120,7 +121,9 @@ class TestPagedAttention:
             n = int(rng.integers(1, maxp + 1))
             bt[b, :n] = rng.choice(P, size=n, replace=False)
             cl[b] = int(rng.integers(1, n * page + 1))
-        out = paged_pallas(q, kp, vp, jnp.asarray(bt), jnp.asarray(cl))
+        out = paged_pallas(
+            q, kp, vp, jnp.asarray(bt), jnp.asarray(cl), interpret=True
+        )
         ref = paged_attention_reference(q, kp, vp, jnp.asarray(bt), jnp.asarray(cl))
         tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
         np.testing.assert_allclose(
@@ -135,7 +138,7 @@ class TestPagedAttention:
         q = rand(jax.random.fold_in(KEY, 25), (B, Hq, D), jnp.float32)
         bt = jnp.asarray([[0, 1, 2, 3], [4, 5, -1, -1]], jnp.int32)
         cl = jnp.asarray([30, 12], jnp.int32)
-        out = paged_pallas(q, kp, vp, bt, cl, softcap=20.0)
+        out = paged_pallas(q, kp, vp, bt, cl, softcap=20.0, interpret=True)
         ref = paged_attention_reference(q, kp, vp, bt, cl, softcap=20.0)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -184,6 +187,22 @@ class TestNBBSKernel:
         )
         assert (np.asarray(t1) == np.asarray(t2)).all()
         assert int(s1["rounds"]) == int(s2["rounds"])
+
+    def test_auto_dispatch_runs_xla_rounds(self, monkeypatch):
+        """Mosaic cannot lower the NBBS kernels, so "auto" names the XLA
+        round bodies on every backend, a TPU included (where the paged
+        attention kernel is still the Pallas one)."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert ops_mod.default_impl() == "pallas"
+        assert ops_mod._resolve_nbbs("auto") == "reference"
+        cfg = TreeConfig(depth=6, max_level=0)
+        levels = jnp.asarray([3, 4, 6, 6], jnp.int32)
+        t1, n1, ok1, _ = nbbs_wavefront_alloc(cfg, cfg.empty_tree(), levels)
+        t2, n2, ok2, _ = wavefront_alloc(
+            cfg, cfg.empty_tree(), levels, jnp.ones(4, bool)
+        )
+        assert (np.asarray(t1) == np.asarray(t2)).all()
+        assert (np.asarray(n1) == np.asarray(n2)).all()
 
     @pytest.mark.parametrize("depth,K,F,seed,layout", [
         (6, 16, 8, 0, "unpacked"), (8, 33, 16, 1, "unpacked"),

@@ -1,0 +1,118 @@
+"""Compile rehearsals for a TPU v5e, without a chip.
+
+The installed TPU compiler compiles for a described v5e topology, so
+these tests catch what interpret mode cannot: a block shape Mosaic
+refuses, a kernel that is not on the served path, a program that does
+not fit.  Nothing runs; the topology is described inside a fixture (so
+that only the worker given this file loads the TPU library), and the
+persistent compile cache is off around the compiles (an entry written
+for a described chip cannot be read back without one).
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.paged_attention import paged_attention
+from repro.models import init_params
+from repro.serve.jit_engine import EngineConfig, engine_step, init_engine_state
+
+KERNEL_CALL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    pytest.importorskip("libtpu", reason="no TPU compiler installed")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _spec(x, sharding, float_dtype=None):
+    dt = x.dtype
+    if float_dtype is not None and jnp.issubdtype(dt, jnp.floating):
+        dt = float_dtype
+    return jax.ShapeDtypeStruct(x.shape, dt, sharding=sharding)
+
+
+def _compile_paged_attention(sharding, H, Hkv, D, dtype, P, page=16, B=8,
+                             max_pages=32):
+    args = [
+        jax.ShapeDtypeStruct((B, H, D), dtype, sharding=sharding),
+        jax.ShapeDtypeStruct((P, page, Hkv, D), dtype, sharding=sharding),
+        jax.ShapeDtypeStruct((P, page, Hkv, D), dtype, sharding=sharding),
+        jax.ShapeDtypeStruct((B, max_pages), jnp.int32, sharding=sharding),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sharding),
+    ]
+    return jax.jit(paged_attention).lower(*args).compile()
+
+
+def test_paged_attention_compiles_at_stablelm_widths(one_chip):
+    cfg = get_config("stablelm-3b")
+    compiled = _compile_paged_attention(
+        one_chip, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+        P=512,
+    )
+    assert KERNEL_CALL in compiled.as_text()
+
+
+@pytest.mark.parametrize("arch", [
+    "phi3-medium-14b", "minitron-4b", "gemma2-27b", "musicgen-large",
+])
+def test_paged_attention_compiles_at_config_heads(one_chip, arch):
+    """Every KV-head count the configs use, at their published head
+    geometry: the [page, Hkv, D] -> [page*Hkv, D] flatten must compile
+    when Hkv is not a multiple of the sublane tile (phi3-medium: 10)."""
+    cfg = get_config(arch)
+    compiled = _compile_paged_attention(
+        one_chip, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+        P=64,
+    )
+    assert KERNEL_CALL in compiled.as_text()
+
+
+@pytest.mark.parametrize("H,Hkv,D,dtype", [
+    (4, 2, 16, jnp.float32), (4, 1, 32, jnp.float32),
+    (8, 2, 32, jnp.bfloat16),
+])
+def test_paged_attention_compiles_at_small_gqa(one_chip, H, Hkv, D, dtype):
+    """The small GQA shapes of the interpret-mode sweep (Hkv 1 and 2)."""
+    compiled = _compile_paged_attention(one_chip, H, Hkv, D, dtype, P=64)
+    assert KERNEL_CALL in compiled.as_text()
+
+
+def test_engine_step_compiles_at_full_width(one_chip):
+    """The served step at stablelm-3b widths, depth cut to 2 layers,
+    bf16 weights: compiles, and runs the Pallas kernel."""
+    cfg = dataclasses.replace(get_config("stablelm-3b"), n_layers=2)
+    ecfg = EngineConfig(
+        arch=cfg, num_pages=512, page_tokens=16, max_batch=8,
+        max_lane_pages=32, max_out=32, dtype="bfloat16", impl="pallas",
+    )
+    params = jax.tree.map(
+        lambda x: _spec(x, one_chip, jnp.bfloat16),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    state = jax.tree.map(
+        lambda x: _spec(x, one_chip),
+        jax.eval_shape(lambda: init_engine_state(ecfg)),
+    )
+    compiled = engine_step.lower(ecfg, params, state).compile()
+    assert KERNEL_CALL in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # the donated KV pool is updated in place, not copied out
+    pool = jax.tree.map(lambda x: x.size * x.dtype.itemsize, state)
+    assert mem.alias_size_in_bytes >= pool.kv_k + pool.kv_v
