@@ -173,6 +173,9 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        # the custom call's name in the compiled program and the
+        # profiler trace, where the benchmark finds the kernel by it
+        name="paged_attention",
     )(
         block_tables.astype(jnp.int32),
         context_lens.astype(jnp.int32),

@@ -10,21 +10,20 @@ plane at a drain boundary (`JitServeEngine.snapshot()` produces one;
     "config": {...engine geometry...},
     "metrics": {name: int | [int, ...]},       # schema-checked names
     "events": [{step, kind, kind_name, ...}],  # drained ring window
-    "spans": [{"phase": "admit"|"decode"|"drain",
+    "spans": [{"phase": "admit"|"decode"|"drain"|"engine.<name>",
                "t0": s, "t1": s, "step0": n, "step1": n, ...}],
   }
 
 `chrome_trace` renders it as a Chrome JSON trace (the array-of-events
 format Perfetto and chrome://tracing both load):
 
-  * host-loop track: one "X" span per recorded host phase (admission
-    bursts, fused decode chunks, drains) at real wall-clock times;
-  * engine-steps track: one span per ring `step` event.  Device steps
-    carry no wall clock (that is the whole point of the in-graph
-    plane), so step times are interpolated inside their enclosing
-    decode chunk's measured window, and each step span is split into
-    schematic alloc -> decode -> retire sub-spans (ordering is real,
-    sub-durations are schematic; counts in args are exact);
+  * host-loop track: one "X" span per journal record (admission
+    bursts, fused decode chunks, drains, and the nested `engine.*`
+    phase spans) at real wall-clock times;
+  * engine-steps track: one span per ring `step` event, its counts in
+    args.  Device steps carry no wall clock (that is the whole point
+    of the in-graph plane), so step times are interpolated inside
+    their enclosing decode chunk's measured window;
   * counter tracks ("C" events) for free pages and active-lane
     occupancy over step time — the Fig. 11 occupancy factor as a
     scrubbable timeline.
@@ -45,9 +44,6 @@ SNAPSHOT_VERSION = 1
 _PID = 1
 _TID_HOST = 1
 _TID_STEPS = 2
-
-# schematic fractions of a step span (ordering real, widths schematic)
-_SUBSPANS = (("alloc", 0.15), ("decode", 0.70), ("retire", 0.15))
 
 
 def validate_snapshot(snap: Dict) -> None:
@@ -139,22 +135,6 @@ def chrome_trace(snap: Dict) -> Dict:
         events.append(
             _span(f"step {ev['step']}", _TID_STEPS, t0, t1 - t0, args)
         )
-        # schematic sub-spans: real ordering, exact counts, split widths
-        cursor = t0
-        detail = {
-            "alloc": {"lanes_won": ev.get("lanes_won", 0),
-                      "lanes_spilled": ev.get("lanes_spilled", 0),
-                      "rounds": ev.get("rounds", 0)},
-            "decode": {},
-            "retire": {"frees_merged": ev.get("frees_merged", 0),
-                       "lanes_overflowed": ev.get("lanes_overflowed", 0)},
-        }
-        for name, frac in _SUBSPANS:
-            dur = frac * (t1 - t0)
-            events.append(
-                _span(name, _TID_STEPS, cursor, dur, detail[name])
-            )
-            cursor += dur
         events.append(_counter("free_pages", t0, ev.get("free_pages", 0)))
         events.append(
             _counter("lanes_won", t0, ev.get("lanes_won", 0))

@@ -61,10 +61,12 @@ final pool occupancy on a replayed trace.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import itertools
 import time
-from collections import Counter
+from collections import Counter, deque
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -624,6 +626,50 @@ _acc_stats = jax.jit(om.merge)
 # The thin host shim
 # ---------------------------------------------------------------------------
 
+# records the span journal holds (about 14 MB of them): some 5,000 pass
+# in a benchmark run's traced span and grace together, at about 10 a
+# 230-ms chunk
+SPAN_JOURNAL_CAP = 1 << 15
+
+
+class SpanJournal:
+    """The newest `cap` records of the host-phase journal, indexed as
+    the list of every record ever appended: `len()` counts them all, and
+    an index or a slice (a `parent`, or a caller's `len()` taken
+    earlier) reads the records still held -- older ones are dropped,
+    `dropped` counts them, and an index into them raises IndexError.
+    Iterating yields the records held, oldest first."""
+
+    def __init__(self, cap: int = SPAN_JOURNAL_CAP) -> None:
+        self._recs: deque = deque(maxlen=cap)
+        self.dropped = 0
+
+    def append(self, rec: Dict) -> None:
+        if len(self._recs) == self._recs.maxlen:
+            self.dropped += 1
+        self._recs.append(rec)
+
+    def __len__(self) -> int:
+        return self.dropped + len(self._recs)
+
+    def __iter__(self):
+        return iter(self._recs)
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            start, stop, step = i.indices(len(self))
+            if step != 1:
+                raise ValueError("SpanJournal slices take no step")
+            start = max(start, self.dropped)
+            return list(itertools.islice(
+                self._recs, start - self.dropped,
+                max(stop, start) - self.dropped))
+        j = (i + len(self) if i < 0 else i) - self.dropped
+        if not 0 <= j < len(self._recs):
+            raise IndexError(f"span {i} is not held (dropped {self.dropped},"
+                             f" held {len(self._recs)})")
+        return self._recs[j]
+
 
 class JitServeEngine:
     """Request-queue shim around the compiled step.
@@ -704,10 +750,13 @@ class JitServeEngine:
             "admit_magazine_spills": 0,
         }
         self.acc = _zero_metrics(self.ecfg)  # device-side totals
-        # host-phase span log for the trace exporter: wall-clock
-        # windows of admissions, fused decode chunks and drains,
-        # relative to engine construction
-        self.spans: List[Dict] = []
+        # host-phase span journal, in seconds since engine construction:
+        # the `engine.*` records of `_span`, and the `admit` / `decode`
+        # / `drain` records that the trace exporter and the benchmark's
+        # `admit_host_ms` read (admissions that admitted, decode chunks,
+        # drains that drained); the newest SPAN_JOURNAL_CAP are held
+        self.spans = SpanJournal()
+        self._open: List[int] = []  # indices of the open `_span` records
         self._t_origin = time.perf_counter()
 
     def _now(self) -> float:
@@ -718,6 +767,26 @@ class JitServeEngine:
             "phase": phase, "t0": t0, "t1": self._now(),
             "step0": step0, "step1": self.stats["steps"], **extra,
         })
+
+    @contextlib.contextmanager
+    def _span(self, name: str, **args):
+        """One host phase, written to both outputs: a profiler
+        annotation `engine.<name>`, which a JAX trace shows beside the
+        device ops, and a journal record with `phase` `engine.<name>`,
+        `t0`/`t1` (enclosing the annotation), `step0`/`step1`, `parent`
+        (the index in `spans` of the enclosing record, or -1) and
+        `args` (`rid` for a span of one request)."""
+        rec = {"phase": f"engine.{name}", "t0": self._now(), "t1": None,
+               "step0": self.stats["steps"], "step1": None,
+               "parent": self._open[-1] if self._open else -1, **args}
+        self._open.append(len(self.spans))
+        self.spans.append(rec)
+        try:
+            with jax.profiler.TraceAnnotation(rec["phase"]):
+                yield rec
+        finally:
+            self._open.pop()
+            rec["t1"], rec["step1"] = self._now(), self.stats["steps"]
 
     # -- admission ----------------------------------------------------
     def _pages_for(self, n_tokens: int) -> int:
@@ -737,75 +806,82 @@ class JitServeEngine:
         self.waiting.append(req)
 
     def _free_lanes(self) -> List[int]:
-        seq = np.asarray(self.state.seq_id)
+        with self._span("sync"):
+            seq = np.asarray(self.state.seq_id)
         return [int(i) for i in np.nonzero(seq < 0)[0]]
 
     def _admit(self) -> None:
-        t0, step0 = self._now(), self.stats["steps"]
-        admitted0 = self.stats["admitted"]
-        free = self._free_lanes()
-        while self.waiting and free:
-            req = self.waiting[0]
-            if self._oversized(req):
+        with self._span("admit") as span:
+            admitted0 = self.stats["admitted"]
+            free = self._free_lanes()
+            while self.waiting and free:
+                req = self.waiting[0]
+                if self._oversized(req):
+                    self.waiting.pop(0)
+                    req.done = True
+                    self.completed[req.req_id] = req
+                    self.stats["rejected"] += 1
+                    continue
+                need = self._pages_for(len(req.prompt) - 1)
+                with self._span("alloc", rid=req.req_id):
+                    (
+                        trees, mag_pages, mag_depth, shards, offs, admitted,
+                        _, fp_h, fp_s, mag_sp,
+                    ) = admit_pages(
+                        self.ecfg, self.state.trees,
+                        self.state.mag_pages, self.state.mag_depth,
+                        jnp.int32(req.req_id), jnp.int32(need),
+                    )
+                    # persist trees+magazines even on failure: an
+                    # exhaustion spill-back moves pages from magazines
+                    # into the tree whether or not the admission fits
+                    self.state = self.state._replace(
+                        trees=trees, mag_pages=mag_pages, mag_depth=mag_depth
+                    )
+                    with self._span("sync"):
+                        fits = bool(admitted)
+                        if self.ecfg.fastpath:
+                            self.stats["admit_fastpath_hits"] += int(fp_h)
+                            self.stats["admit_fastpath_spills"] += int(fp_s)
+                        if self.ecfg.magazines:
+                            self.stats["admit_magazine_spills"] += int(mag_sp)
+                if not fits:
+                    self.stats["queued_full"] += 1
+                    break  # pool full: natural admission control
                 self.waiting.pop(0)
-                req.done = True
-                self.completed[req.req_id] = req
-                self.stats["rejected"] += 1
-                continue
-            need = self._pages_for(len(req.prompt) - 1)
-            (
-                trees, mag_pages, mag_depth, shards, offs, admitted,
-                _, fp_h, fp_s, mag_sp,
-            ) = admit_pages(
-                self.ecfg, self.state.trees,
-                self.state.mag_pages, self.state.mag_depth,
-                jnp.int32(req.req_id), jnp.int32(need),
-            )
-            # persist trees+magazines even on failure: an exhaustion
-            # spill-back moves pages from magazines into the tree
-            # whether or not the admission ultimately fits
-            self.state = self.state._replace(
-                trees=trees, mag_pages=mag_pages, mag_depth=mag_depth
-            )
-            if self.ecfg.fastpath:  # admission already syncs on `admitted`
-                self.stats["admit_fastpath_hits"] += int(fp_h)
-                self.stats["admit_fastpath_spills"] += int(fp_s)
-            if self.ecfg.magazines:
-                self.stats["admit_magazine_spills"] += int(mag_sp)
-            if not bool(admitted):
-                self.stats["queued_full"] += 1
-                break  # pool full: natural admission control
-            self.waiting.pop(0)
-            self._insert(free.pop(0), req, shards, offs, need)
-            self.stats["admitted"] += 1
-        n_adm = self.stats["admitted"] - admitted0
-        if n_adm:
-            self._record_span("admit", t0, step0, admitted=n_adm)
+                self._insert(free.pop(0), req, shards, offs, need)
+                self.stats["admitted"] += 1
+            n_adm = self.stats["admitted"] - admitted0
+            if n_adm:
+                self._record_span("admit", span["t0"], span["step0"],
+                                  admitted=n_adm)
 
     def _insert(self, lane: int, req: Request, shards, offs, n_pages) -> None:
         S = len(req.prompt)
         arch, ecfg = self.cfg, self.ecfg
         Spad = _next_pow2(S)
-        if S > 1:
-            toks = np.zeros((1, Spad), np.int32)
-            toks[0, :S] = req.prompt
-            _, cache = serve_prefill(
-                arch, self.params, {"tokens": jnp.asarray(toks)},
-                max_len=Spad, dtype=ecfg.jdtype,
+        with self._span("prefill", rid=req.req_id):
+            if S > 1:
+                toks = np.zeros((1, Spad), np.int32)
+                toks[0, :S] = req.prompt
+                _, cache = serve_prefill(
+                    arch, self.params, {"tokens": jnp.asarray(toks)},
+                    max_len=Spad, dtype=ecfg.jdtype,
+                )
+                cache_k, cache_v = cache["k"][:, 0], cache["v"][:, 0]
+            else:
+                kv_shape = (
+                    arch.n_layers, Spad, arch.n_kv_heads, arch.head_dim
+                )
+                cache_k = jnp.zeros(kv_shape, ecfg.jdtype)
+                cache_v = jnp.zeros(kv_shape, ecfg.jdtype)
+        with self._span("insert", rid=req.req_id):
+            self.state = prefill_insert(
+                ecfg, self.state,
+                jnp.int32(lane), jnp.int32(req.req_id), shards, offs,
+                jnp.int32(n_pages), jnp.int32(S - 1), cache_k, cache_v,
+                jnp.int32(req.prompt[S - 1]), jnp.int32(req.max_new_tokens),
             )
-            cache_k, cache_v = cache["k"][:, 0], cache["v"][:, 0]
-        else:
-            kv_shape = (
-                arch.n_layers, Spad, arch.n_kv_heads, arch.head_dim
-            )
-            cache_k = jnp.zeros(kv_shape, ecfg.jdtype)
-            cache_v = jnp.zeros(kv_shape, ecfg.jdtype)
-        self.state = prefill_insert(
-            ecfg, self.state,
-            jnp.int32(lane), jnp.int32(req.req_id), shards, offs,
-            jnp.int32(n_pages), jnp.int32(S - 1), cache_k, cache_v,
-            jnp.int32(req.prompt[S - 1]), jnp.int32(req.max_new_tokens),
-        )
         self.running[req.req_id] = req
         self._lane_of[req.req_id] = lane
 
@@ -813,8 +889,7 @@ class JitServeEngine:
     def decode_steps(self, n: int, *, fused: bool = False) -> None:
         """Run n compiled decode iterations with no host sync.  With
         `fused=True` the whole chunk is one `lax.scan` dispatch."""
-        t0, step0 = self._now(), self.stats["steps"]
-        with jax.profiler.TraceAnnotation("engine.decode_steps"):
+        with self._span("decode_steps") as span:
             if fused:
                 self.state, traj = engine_run(
                     self.ecfg, self.params, self.state, n
@@ -826,41 +901,47 @@ class JitServeEngine:
                         self.ecfg, self.params, self.state
                     )
                     self.acc = _acc_stats(self.acc, stat)
-        self.stats["steps"] += n
-        self._record_span("decode", t0, step0, n=n, fused=int(fused))
+            self.stats["steps"] += n
+            self._record_span("decode", span["t0"], span["step0"], n=n,
+                              fused=int(fused))
 
     def _drain(self) -> List[int]:
         """Collect retired lanes (one host sync), clear them, and
         return the drained seq ids in retirement-step order."""
-        t0, step0 = self._now(), self.stats["steps"]
-        seq, act, n_out, out_toks, over, done = jax.device_get((
-            self.state.seq_id, self.state.active, self.state.n_out,
-            self.state.out_toks, self.state.overflowed,
-            self.state.done_step,
-        ))
-        lanes = np.nonzero((seq >= 0) & ~act)[0]
-        # deterministic retirement order: by retire step, then lane id
-        lanes = sorted(lanes, key=lambda i: (int(done[i]), int(i)))
-        drained = []
-        for lane in lanes:
-            sid = int(seq[lane])
-            req = self.running.pop(sid)
-            self._lane_of.pop(sid)
-            req.out_tokens = [int(t) for t in out_toks[lane, : n_out[lane]]]
-            req.done = True
-            self.completed[sid] = req
-            self.done_steps[sid] = int(done[lane])
-            self.retired_order.append(sid)
-            if over[lane]:
-                self.stats["overflow_retired"] += 1
-            drained.append(sid)
-        if drained:
-            mask = np.zeros((self.ecfg.max_batch,), bool)
-            mask[list(lanes)] = True
-            self.state = clear_lanes(
-                self.ecfg, self.state, jnp.asarray(mask)
-            )
-            self._record_span("drain", t0, step0, drained=len(drained))
+        with self._span("drain") as span:
+            with self._span("sync"):
+                seq, act, n_out, out_toks, over, done = jax.device_get((
+                    self.state.seq_id, self.state.active, self.state.n_out,
+                    self.state.out_toks, self.state.overflowed,
+                    self.state.done_step,
+                ))
+            lanes = np.nonzero((seq >= 0) & ~act)[0]
+            # deterministic retirement order: by retire step, then lane id
+            lanes = sorted(lanes, key=lambda i: (int(done[i]), int(i)))
+            drained = []
+            for lane in lanes:
+                sid = int(seq[lane])
+                req = self.running.pop(sid)
+                self._lane_of.pop(sid)
+                req.out_tokens = [
+                    int(t) for t in out_toks[lane, : n_out[lane]]
+                ]
+                req.done = True
+                self.completed[sid] = req
+                self.done_steps[sid] = int(done[lane])
+                self.retired_order.append(sid)
+                if over[lane]:
+                    self.stats["overflow_retired"] += 1
+                drained.append(sid)
+            if drained:
+                mask = np.zeros((self.ecfg.max_batch,), bool)
+                mask[list(lanes)] = True
+                with self._span("clear"):
+                    self.state = clear_lanes(
+                        self.ecfg, self.state, jnp.asarray(mask)
+                    )
+                self._record_span("drain", span["t0"], span["step0"],
+                                  drained=len(drained))
         return drained
 
     # -- ServeEngine-compatible surface --------------------------------
@@ -873,7 +954,8 @@ class JitServeEngine:
         if not self.running:
             return 0
         self.decode_steps(1)
-        return int(np.asarray(self.state.active).sum())
+        with self._span("sync"):
+            return int(np.asarray(self.state.active).sum())
 
     def run_to_completion(
         self, max_steps: int = 10_000, chunk: int = 1
@@ -917,7 +999,8 @@ class JitServeEngine:
         for k in host:
             acc.setdefault(k, jnp.int32(0))
         base = {k: host.get(k, jnp.zeros_like(v)) for k, v in acc.items()}
-        return om.to_host(om.merge(base, acc))
+        with self._span("sync"):
+            return om.to_host(om.merge(base, acc))
 
     def snapshot(self) -> Dict[str, object]:
         """Drain the whole telemetry plane into the exporter's snapshot
@@ -925,6 +1008,9 @@ class JitServeEngine:
         event ring's surviving window, and the host-phase span log.
         This is a deliberate host sync — call it at run boundaries."""
         ecfg = self.ecfg
+        metrics = self.stat_totals()
+        with self._span("sync"):
+            events = oring.drain(self.state.ring)
         return {
             "obs_schema": SNAPSHOT_VERSION,
             "source": "jit_engine",
@@ -939,24 +1025,25 @@ class JitServeEngine:
                 "magazines": ecfg.magazines,
                 "ring_capacity": ecfg.ring_capacity,
             },
-            "metrics": self.stat_totals(),
-            "events": oring.drain(self.state.ring),
+            "metrics": metrics,
+            "events": events,
             "spans": list(self.spans),
         }
 
     def device_free_pages(self) -> int:
-        free = int(
-            pool_free_units(self.ecfg.pool_config(), self.state.trees).sum()
-        )
-        if self.ecfg.magazines:  # stashed pages are instantly claimable
-            free += int(self.state.mag_depth.sum())
+        with self._span("sync"):
+            free = int(pool_free_units(
+                self.ecfg.pool_config(), self.state.trees).sum())
+            if self.ecfg.magazines:  # stashed pages are instantly claimable
+                free += int(self.state.mag_depth.sum())
         return free
 
     def device_block_table(self, seq_id: int) -> np.ndarray:
         """Global-page-id table of one running sequence (debug/test
         sync; mirrors `PagedKVManager.block_table` numbering)."""
         lane = self._lane_of[seq_id]
-        tables = global_tables(
-            self.ecfg, self.state.page_shard, self.state.page_off
-        )
-        return np.asarray(tables[lane])
+        with self._span("sync"):
+            tables = global_tables(
+                self.ecfg, self.state.page_shard, self.state.page_off
+            )
+            return np.asarray(tables[lane])

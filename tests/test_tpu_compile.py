@@ -11,6 +11,7 @@ for a described chip cannot be read back without one).
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.paged_attention import paged_attention
 from repro.models import init_params
-from repro.serve.jit_engine import EngineConfig, engine_step, init_engine_state
+from repro.serve.jit_engine import (
+    EngineConfig, engine_run, engine_step, init_engine_state,
+)
 
 KERNEL_CALL = "tpu_custom_call"
 
@@ -94,25 +97,45 @@ def test_paged_attention_compiles_at_small_gqa(one_chip, H, Hkv, D, dtype):
     assert KERNEL_CALL in compiled.as_text()
 
 
-def test_engine_step_compiles_at_full_width(one_chip):
-    """The served step at stablelm-3b widths, depth cut to 2 layers,
-    bf16 weights: compiles, and runs the Pallas kernel."""
+def _served_engine(sharding):
+    """The served engine's configuration at stablelm-3b widths, depth
+    cut to 2 layers, with the shapes of its bf16 weights and state."""
     cfg = dataclasses.replace(get_config("stablelm-3b"), n_layers=2)
     ecfg = EngineConfig(
         arch=cfg, num_pages=512, page_tokens=16, max_batch=8,
         max_lane_pages=32, max_out=32, dtype="bfloat16", impl="pallas",
     )
     params = jax.tree.map(
-        lambda x: _spec(x, one_chip, jnp.bfloat16),
+        lambda x: _spec(x, sharding, jnp.bfloat16),
         jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))),
     )
     state = jax.tree.map(
-        lambda x: _spec(x, one_chip),
+        lambda x: _spec(x, sharding),
         jax.eval_shape(lambda: init_engine_state(ecfg)),
     )
+    return ecfg, params, state
+
+
+def test_engine_step_compiles_at_full_width(one_chip):
+    """The served step at stablelm-3b widths, depth cut to 2 layers,
+    bf16 weights: compiles, and runs the Pallas kernel."""
+    ecfg, params, state = _served_engine(one_chip)
     compiled = engine_step.lower(ecfg, params, state).compile()
     assert KERNEL_CALL in compiled.as_text()
     mem = compiled.memory_analysis()
     # the donated KV pool is updated in place, not copied out
     pool = jax.tree.map(lambda x: x.size * x.dtype.itemsize, state)
     assert mem.alias_size_in_bytes >= pool.kv_k + pool.kv_v
+
+
+def test_engine_run_names_the_kernel(one_chip):
+    """The fused chunk the server dispatches holds the kernel as a
+    custom call named `paged_attention`: the profiler trace names its
+    device op after that instruction, and the benchmark's reduction
+    finds the kernel by that name."""
+    ecfg, params, state = _served_engine(one_chip)
+    text = engine_run.lower(ecfg, params, state, 2).compile().as_text()
+    named = re.findall(
+        r"^\s*%(paged_attention(?:\.\d+)?) = .*custom-call\(.*"
+        r'custom_call_target="tpu_custom_call"', text, re.MULTILINE)
+    assert named
