@@ -100,10 +100,10 @@ def kernel_error(cfg, rng) -> float:
         n = int(rng.integers(1, MAX_LANE_PAGES + 1))
         tables[b, :n] = rng.choice(NUM_PAGES, size=n, replace=False)
         ctx[b] = int(rng.integers(1, n * PAGE_TOKENS + 1))
-    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(ctx))
-    out = ops.paged_attention(*args)
+    args = (jnp.asarray(tables), jnp.asarray(ctx))
+    out = ops.paged_attention(q, kp[None], vp[None], 0, *args)
     with jax.default_matmul_precision("highest"):
-        ref = paged_attention_reference(*args)
+        ref = paged_attention_reference(q, kp, vp, *args)
     out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
     check(out.shape == (MAX_BATCH, H, D), f"kernel output shape {out.shape}")
     check(np.isfinite(out).all(), "kernel output has non-finite values")

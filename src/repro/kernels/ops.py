@@ -147,6 +147,7 @@ def paged_attention(
     q: Array,
     k_pages: Array,
     v_pages: Array,
+    layer: Array,
     block_tables: Array,
     context_lens: Array,
     *,
@@ -154,12 +155,14 @@ def paged_attention(
     scale: Optional[float] = None,
     impl: str = "auto",
 ) -> Array:
+    """Decode attention over layer `layer` of the stacked pools
+    k/v_pages: [L,P,page,Hkv,D]."""
     impl = _resolve(impl)
     if impl == "reference":
         return kref.paged_attention_reference(
             q,
-            k_pages,
-            v_pages,
+            k_pages[layer],
+            v_pages[layer],
             block_tables,
             context_lens,
             softcap=softcap,
@@ -169,6 +172,7 @@ def paged_attention(
         q,
         k_pages,
         v_pages,
+        layer,
         block_tables,
         context_lens,
         softcap=softcap,

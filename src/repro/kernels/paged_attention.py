@@ -14,8 +14,14 @@ Grid: (batch, pages); pages innermost with fp32 online-softmax scratch,
 invalid pages (table id < 0, or beyond the sequence's context length)
 skipped with @pl.when.  Each grid step covers *all* heads of one page,
 so every block keeps its last two dimensions whole — q/o blocks are
-(1, Hq, D) and k/v blocks (1, page, Hkv, D) — which is what Mosaic
+(1, Hq, D) and k/v blocks (1, 1, page, Hkv, D) — which is what Mosaic
 requires when D (80 for stablelm-3b) is not a multiple of 128.
+
+The kernel takes the model's stacked pools `[L, P, page, Hkv, D]` and
+the layer to read as a prefetched scalar; the k/v index maps steer each
+DMA at (layer, page).  A sliced one-layer operand would make XLA copy
+that layer's whole pool, every page, before each call.  A caller that
+holds one layer passes `pool[None]` and layer 0.
 
 The page's K/V are flattened to [page*Hkv, D] (row r = slot r // Hkv,
 kv head r % Hkv) so both products are plain 2-D matmuls over all
@@ -61,6 +67,7 @@ def _paged_decode_kernel(
     page: int,
     group: int,
     # prefetched scalars
+    layer_ref,
     tables_ref,
     lens_ref,
     # tensor refs
@@ -88,10 +95,10 @@ def _paged_decode_kernel(
 
     @pl.when(live)
     def _compute():
-        _, _, hkv, d = k_ref.shape
+        _, _, _, hkv, d = k_ref.shape
         q = q_ref[0].astype(jnp.float32)  # [Hq, D]
-        k = k_ref[0].astype(jnp.float32).reshape(page * hkv, d)
-        v = v_ref[0].astype(jnp.float32).reshape(page * hkv, d)
+        k = k_ref[0, 0].astype(jnp.float32).reshape(page * hkv, d)
+        v = v_ref[0, 0].astype(jnp.float32).reshape(page * hkv, d)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [Hq, page*Hkv]
@@ -129,6 +136,7 @@ def paged_attention(
     q: Array,
     k_pages: Array,
     v_pages: Array,
+    layer: Array,
     block_tables: Array,
     context_lens: Array,
     *,
@@ -136,9 +144,10 @@ def paged_attention(
     scale: Optional[float] = None,
     interpret: bool = False,
 ) -> Array:
-    """q: [B,Hq,D]; k/v_pages: [P,page,Hkv,D]; tables: [B,max_pages]."""
+    """q: [B,Hq,D]; k/v_pages: [L,P,page,Hkv,D]; layer: int32 scalar or
+    [1], the layer of the pools to attend over; tables: [B,max_pages]."""
     B, Hq, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    _, P, page, Hkv, _ = k_pages.shape
     assert Hq % Hkv == 0
     group = Hq // Hkv
     max_pages = block_tables.shape[1]
@@ -147,19 +156,19 @@ def paged_attention(
 
     kernel = functools.partial(_paged_decode_kernel, scale, softcap, page, group)
 
-    def q_map(b, j, tables, lens):
+    def q_map(b, j, layer, tables, lens):
         return (b, 0, 0)
 
-    def kv_map(b, j, tables, lens):
-        return (jnp.maximum(tables[b, j], 0), 0, 0, 0)
+    def kv_map(b, j, layer, tables, lens):
+        return (layer[0], jnp.maximum(tables[b, j], 0), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, max_pages),
         in_specs=[
             pl.BlockSpec((1, Hq, D), q_map),
-            pl.BlockSpec((1, page, Hkv, D), kv_map),
-            pl.BlockSpec((1, page, Hkv, D), kv_map),
+            pl.BlockSpec((1, 1, page, Hkv, D), kv_map),
+            pl.BlockSpec((1, 1, page, Hkv, D), kv_map),
         ],
         out_specs=pl.BlockSpec((1, Hq, D), q_map),
         scratch_shapes=[
@@ -177,6 +186,7 @@ def paged_attention(
         # profiler trace, where the benchmark finds the kernel by it
         name="paged_attention",
     )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
         block_tables.astype(jnp.int32),
         context_lens.astype(jnp.int32),
         q,

@@ -118,10 +118,13 @@ def paged_decode_step(
         # redirected to the OOB page above and are dropped here)
         pk = pk.at[layer, page_idx, slot].set(k[:, 0], mode="drop")
         pv = pv.at[layer, page_idx, slot].set(v[:, 0], mode="drop")
+        # the kernel reads this layer's pages from the stacked pools;
+        # a `pk[layer]` operand would be copied whole at every layer
         o = ops.paged_attention(
             q[:, 0],
-            pk[layer],
-            pv[layer],
+            pk,
+            pv,
+            layer,
             block_tables,
             ctx_att,
             softcap=cfg.attn_softcap or None,

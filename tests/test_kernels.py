@@ -102,6 +102,39 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
+def _paged_case(seed, L, P, page, maxp, B, Hq, Hkv, D, dtype):
+    """A stacked pool [L, P, page, Hkv, D] with distinct data per layer,
+    -1-padded tables of distinct pages and contexts inside them; the
+    last lane is idle (context 0, no pages), as the engine passes an
+    inactive lane."""
+    key = jax.random.fold_in(KEY, seed)
+    kp = rand(jax.random.fold_in(key, 0), (L, P, page, Hkv, D), dtype)
+    vp = rand(jax.random.fold_in(key, 1), (L, P, page, Hkv, D), dtype)
+    q = rand(jax.random.fold_in(key, 2), (B, Hq, D), dtype)
+    rng = np.random.default_rng(seed)
+    bt = np.full((B, maxp), -1, np.int32)
+    cl = np.zeros((B,), np.int32)
+    for b in range(B - 1):
+        n = int(rng.integers(1, maxp + 1))
+        bt[b, :n] = rng.choice(P, size=n, replace=False)
+        cl[b] = int(rng.integers(1, n * page + 1))
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(cl)
+
+
+def _check_paged(out, q, kp, vp, layer, bt, cl, tol, kernel=True):
+    """`out` is the attention over layer `layer` of the stacked pools:
+    it matches the reference on that layer's pool in every live lane.
+    The kernel's is zero in an idle lane; the reference's softmax over
+    an empty context is undefined there."""
+    ref = paged_attention_reference(q, kp[layer], vp[layer], bt, cl)
+    out = np.asarray(out, np.float32)
+    live = np.asarray(cl) > 0
+    np.testing.assert_allclose(
+        out[live], np.asarray(ref, np.float32)[live], atol=tol, rtol=tol,
+    )
+    assert not (kernel and out[~live].any())
+
+
 class TestPagedAttention:
     @pytest.mark.parametrize("page,maxp,Hq,Hkv,D", [
         (16, 8, 4, 2, 64),
@@ -110,26 +143,17 @@ class TestPagedAttention:
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_sweep(self, page, maxp, Hq, Hkv, D, dtype):
-        B, P = 3, 64
-        kp = rand(jax.random.fold_in(KEY, 20), (P, page, Hkv, D), dtype)
-        vp = rand(jax.random.fold_in(KEY, 21), (P, page, Hkv, D), dtype)
-        q = rand(jax.random.fold_in(KEY, 22), (B, Hq, D), dtype)
-        rng = np.random.default_rng(0)
-        bt = np.full((B, maxp), -1, np.int32)
-        cl = np.zeros((B,), np.int32)
-        for b in range(B):
-            n = int(rng.integers(1, maxp + 1))
-            bt[b, :n] = rng.choice(P, size=n, replace=False)
-            cl[b] = int(rng.integers(1, n * page + 1))
-        out = paged_pallas(
-            q, kp, vp, jnp.asarray(bt), jnp.asarray(cl), interpret=True
+        """At every layer of a stacked pool, the kernel matches the
+        reference on that layer's pool: dead slots, an idle lane, GQA
+        and MHA."""
+        L, B, P = 3, 4, 64
+        q, kp, vp, bt, cl = _paged_case(
+            20, L, P, page, maxp, B, Hq, Hkv, D, dtype
         )
-        ref = paged_attention_reference(q, kp, vp, jnp.asarray(bt), jnp.asarray(cl))
         tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            atol=tol, rtol=tol,
-        )
+        for layer in range(L):
+            out = paged_pallas(q, kp, vp, layer, bt, cl, interpret=True)
+            _check_paged(out, q, kp, vp, layer, bt, cl, tol)
 
     def test_softcap(self):
         B, P, page, maxp, Hq, Hkv, D = 2, 16, 8, 4, 4, 2, 32
@@ -138,9 +162,36 @@ class TestPagedAttention:
         q = rand(jax.random.fold_in(KEY, 25), (B, Hq, D), jnp.float32)
         bt = jnp.asarray([[0, 1, 2, 3], [4, 5, -1, -1]], jnp.int32)
         cl = jnp.asarray([30, 12], jnp.int32)
-        out = paged_pallas(q, kp, vp, bt, cl, softcap=20.0, interpret=True)
+        out = paged_pallas(
+            q, kp[None], vp[None], 0, bt, cl, softcap=20.0, interpret=True
+        )
         ref = paged_attention_reference(q, kp, vp, bt, cl, softcap=20.0)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+    @pytest.mark.parametrize("impl", ["interpret", "reference"])
+    @pytest.mark.parametrize("Hq,Hkv", [(6, 2), (4, 4)])
+    def test_layer_traced_in_scan(self, impl, Hq, Hkv):
+        """The layer as `paged_decode_step` passes it: a traced index of
+        a `lax.scan` over the layers, with the stacked pools as the
+        scan's carry, through `ops.paged_attention`."""
+        L, B, P, page, maxp, D = 4, 3, 32, 8, 6, 32
+        q, kp, vp, bt, cl = _paged_case(
+            26, L, P, page, maxp, B, Hq, Hkv, D, jnp.float32
+        )
+
+        @jax.jit
+        def per_layer(kp, vp):
+            def body(carry, layer):
+                pk, pv = carry
+                o = paged_attention(q, pk, pv, layer, bt, cl, impl=impl)
+                return (pk, pv), o
+
+            return jax.lax.scan(body, (kp, vp), jnp.arange(L))[1]
+
+        outs = per_layer(kp, vp)
+        for layer in range(L):
+            _check_paged(outs[layer], q, kp, vp, layer, bt, cl, 2e-5,
+                         kernel=impl != "reference")
 
 
 class TestNBBSKernel:

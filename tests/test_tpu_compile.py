@@ -52,11 +52,14 @@ def _spec(x, sharding, float_dtype=None):
 
 
 def _compile_paged_attention(sharding, H, Hkv, D, dtype, P, page=16, B=8,
-                             max_pages=32):
+                             max_pages=32, L=2):
+    """The kernel on stacked pools of L layers, the layer a traced
+    argument as the decode step's layer scan passes it."""
     args = [
         jax.ShapeDtypeStruct((B, H, D), dtype, sharding=sharding),
-        jax.ShapeDtypeStruct((P, page, Hkv, D), dtype, sharding=sharding),
-        jax.ShapeDtypeStruct((P, page, Hkv, D), dtype, sharding=sharding),
+        jax.ShapeDtypeStruct((L, P, page, Hkv, D), dtype, sharding=sharding),
+        jax.ShapeDtypeStruct((L, P, page, Hkv, D), dtype, sharding=sharding),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=sharding),
         jax.ShapeDtypeStruct((B, max_pages), jnp.int32, sharding=sharding),
         jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sharding),
     ]
@@ -97,12 +100,13 @@ def test_paged_attention_compiles_at_small_gqa(one_chip, H, Hkv, D, dtype):
     assert KERNEL_CALL in compiled.as_text()
 
 
-def _served_engine(sharding):
-    """The served engine's configuration at stablelm-3b widths, depth
-    cut to 2 layers, with the shapes of its bf16 weights and state."""
-    cfg = dataclasses.replace(get_config("stablelm-3b"), n_layers=2)
+def _served_engine(sharding, arch="stablelm-3b", num_pages=512):
+    """The served engine's configuration at an arch's widths (stablelm-3b
+    by default), depth cut to 2 layers, with the shapes of its bf16
+    weights and state."""
+    cfg = dataclasses.replace(get_config(arch), n_layers=2)
     ecfg = EngineConfig(
-        arch=cfg, num_pages=512, page_tokens=16, max_batch=8,
+        arch=cfg, num_pages=num_pages, page_tokens=16, max_batch=8,
         max_lane_pages=32, max_out=32, dtype="bfloat16", impl="pallas",
     )
     params = jax.tree.map(
@@ -139,3 +143,29 @@ def test_engine_run_names_the_kernel(one_chip):
         r"^\s*%(paged_attention(?:\.\d+)?) = .*custom-call\(.*"
         r'custom_call_target="tpu_custom_call"', text, re.MULTILINE)
     assert named
+
+
+@pytest.mark.parametrize("arch,num_pages", [
+    ("stablelm-3b", 512),  # 32 heads of dim 80 (padded to the lane tile)
+    ("minitron-4b", 1024),  # 24 q heads over 8 kv heads of dim 128
+])
+def test_engine_run_reads_the_stacked_pool(one_chip, arch, num_pages):
+    """The kernel addresses its layer in the stacked pools itself: no
+    instruction of the fused chunk outputs one layer of a pool (a
+    `pool[layer]` operand would be copied whole, every page, at every
+    layer of every step), and the kernel's k/v operands are the
+    `[L, P, page, Hkv, D]` pools."""
+    ecfg, params, state = _served_engine(one_chip, arch, num_pages)
+    cfg = ecfg.arch
+    text = engine_run.lower(ecfg, params, state, 2).compile().as_text()
+    layer = f"{num_pages},{ecfg.page_tokens},{cfg.n_kv_heads},{cfg.head_dim}"
+    sliced = re.findall(rf"^\s*(%\S+) = bf16\[{layer}\]", text, re.MULTILINE)
+    assert not sliced
+    calls = re.findall(
+        r"^\s*%paged_attention(?:\.\d+)? = .*custom-call\(.*"
+        r"operand_layout_constraints=\{(.*?)\}\}", text, re.MULTILINE)
+    assert calls
+    stacked = f"bf16[{cfg.n_layers},{layer}]"
+    for operands in calls:
+        k, v = re.findall(r"\w+\[[\d,]*\]", operands)[-2:]
+        assert k == v == stacked
