@@ -6,13 +6,14 @@ benchmark's own comparison.
 
 For each seed it builds the engine, serves the cell's own traffic for
 a short window at the cell's load (as `run.py` does), reads the exact
-counts of that window, and runs the plain reference over the same
-sample of served requests that a benchmark run compares, with the
-control beside it: the reference with its weights rounded to float8
-e4m3 (reference.py). Both go through `run.compare` with the cell's
-limits, the program's gap in one and the control's in its place in
-the other, and it prints, per seed, both gaps and both verdicts: the
-program's `correct` has to read true and the control's false. The
+counts of that window, and runs the configuration's plain reference
+(`spec.reference`) over the same sample of served requests that a
+benchmark run compares, with the control beside it: for
+`bench/reference.py`, the reference with its weights rounded to float8
+e4m3. Both go through `run.compare` with the cell's limits, the
+program's gap in one and the control's in its place in the other, and
+it prints, per seed, both gaps and both verdicts: the program's
+`correct` has to read true and the control's false. The
 lower reading of the limit is the largest program gap over a dozen
 seeds or more, the upper one the smallest control gap (PERF.md gives
 both and the limit). The benchmark's own runs do not run the control.

@@ -8,7 +8,10 @@ of its roofline, and no share can pass 100% unless the time leaves out
 part of the work.
 
 Sizes come from a configuration file (`bench/configs/<name>.json`);
-bytes are those of the served dtype (2 for bfloat16).
+bytes are those of the served dtype (2 for bfloat16). Where the file
+has Hugging Face's `layer_types` and `sliding_window`, a
+`sliding_attention` layer attends over at most the window's newest
+positions; without them every layer attends over the whole context.
 """
 
 from __future__ import annotations
@@ -25,6 +28,23 @@ def _dims(cfg: dict):
             BYTES[cfg["torch_dtype"]])
 
 
+def attended_positions(cfg: dict, attended: int) -> int:
+    """Positions one token attends over, summed over the layers: the
+    whole context at a full layer, at most `sliding_window` of it at a
+    sliding one."""
+    L = cfg["num_hidden_layers"]
+    kinds = cfg.get("layer_types")
+    if kinds is None:
+        return attended * L
+    if len(kinds) != L or not set(kinds) <= {"full_attention",
+                                             "sliding_attention"}:
+        raise ValueError(f"layer_types must give one of full_attention, "
+                         f"sliding_attention for each of {L} layers")
+    window = min(attended, cfg["sliding_window"])
+    n_sliding = kinds.count("sliding_attention")
+    return attended * (L - n_sliding) + window * n_sliding
+
+
 def layer_params(cfg: dict) -> int:
     """Weights of one block: q, k, v, o and the three SwiGLU matrices."""
     L, d, H, Hkv, D, ff, V, _ = _dims(cfg)
@@ -39,26 +59,28 @@ def decode_params(cfg: dict) -> int:
 
 
 def attn_kernel_work(cfg: dict, attended: int) -> Tuple[float, float]:
-    """FLOPs and bytes of paged decode attention for one token that
-    attends over `attended` positions, summed over the layers:
-    q.k and p.v (4 * ctx * Hq * D FLOPs), the live K and V read once,
-    q read and the output written."""
+    """FLOPs and bytes of paged decode attention for one token whose
+    context holds `attended` positions, summed over the layers: q.k and
+    p.v (4 * positions * Hq * D FLOPs), the K and V it attends to read
+    once, q read and the output written."""
     L, d, H, Hkv, D, ff, V, b = _dims(cfg)
-    flops = 4.0 * attended * H * D * L
-    byts = (2.0 * attended * Hkv * D * b + 2.0 * H * D * b) * L
+    pos = attended_positions(cfg, attended)
+    flops = 4.0 * pos * H * D
+    byts = 2.0 * pos * Hkv * D * b + 2.0 * H * D * b * L
     return flops, byts
 
 
 def step_work(cfg: dict, attended: Iterable[int]) -> Tuple[float, float]:
     """Model FLOPs and HBM bytes one decode step needs for the lanes
-    whose tokens attend over `attended` positions: 2 FLOPs per weight
-    per token plus attention; all weights read once, the live K and V
-    read, the new K and V written."""
+    whose contexts hold `attended` positions: 2 FLOPs per weight per
+    token plus attention; all weights read once, the K and V each token
+    attends to read, the new K and V written."""
     L, d, H, Hkv, D, ff, V, b = _dims(cfg)
     P = decode_params(cfg)
     flops = 0.0
     byts = float(P * b)
     for a in attended:
-        flops += 2.0 * P + 4.0 * a * H * D * L
-        byts += 2.0 * a * Hkv * D * b * L + 2.0 * Hkv * D * b * L
+        pos = attended_positions(cfg, a)
+        flops += 2.0 * P + 4.0 * pos * H * D
+        byts += 2.0 * pos * Hkv * D * b + 2.0 * Hkv * D * b * L
     return flops, byts

@@ -7,9 +7,11 @@ weights made on the device from the seed, JAX's persistent compile
 cache in `.jax_cache/` at the checkout root) at the cell's
 configuration, warms up every shape the cell's traffic uses, offers
 the traffic for `--seconds`, serves on until every admitted request
-has finished, and checks what was served against the plain reference
-(`reference.py`). With `--trace 1` a profiler trace of part of the
-window gives the per-layer metrics instead of the end-to-end ones.
+has finished, and checks what was served against the configuration's
+plain reference (`spec.reference`: `bench/reference.py`, or the
+`bench/references/<name>.py` that the configuration file names). With
+`--trace 1` a profiler trace of part of the window gives the per-layer
+metrics instead of the end-to-end ones.
 
 Earlier lines (standard error) give the set-up breakdown, compiles
 inside the window, the generator's lateness, the engine's counters
@@ -178,15 +180,13 @@ def window_counts(eng, win, num_pages: int, seconds: float) -> dict:
 
 def reference_gaps(win, cfg_file: dict, seed: int, check: dict,
                    control: bool = False) -> dict:
-    """The reference's logit gaps over the sample of finished requests
-    (with `control`, the control's beside them)."""
+    """The configuration's reference's logit gaps over the sample of
+    finished requests (with `control`, the control's beside them)."""
     sample = sample_for_check(win, seed, check)
     if not sample:
         return {"requests": 0, "tokens": 0, "gap": math.inf,
                 "control_gap": math.inf}
-    import reference
-
-    gaps = reference.logit_gaps(
+    gaps = spec.reference(cfg_file).logit_gaps(
         cfg_file, seed,
         [{"prompt": r.prompt, "served": np.asarray(r.out)} for r in sample],
         tuple(check["shape"]), control=control)

@@ -10,13 +10,14 @@ the chunk, so its time is the chunk's end, and it says which requests
 got which tokens. The engine would wait at the next drain anyway.
 
 A request is handed to the engine only while the pool can hold the
-whole output of every request handed over and not yet finished
-(`traffic.pages_for`, against the pool's pages): the engine reserves
-only a prompt's pages at admission and cannot preempt, so a lane that
-found the pool empty in decode would be cut short. Requests that do
-not fit yet wait in order, on the harness's side, and their wait counts
-in their time to first token. The read at each chunk end also takes
-the pages the lanes hold, for the pool's occupancy.
+whole output of every request handed over and not yet finished (the
+most pages each can hold, `pages.request_pages`, against the pool's
+pages): the engine reserves only a prompt's pages at admission and
+cannot preempt, so a lane that found the pool empty in decode would be
+cut short. Requests that do not fit yet wait in order, on the
+harness's side, and their wait counts in their time to first token.
+The read at each chunk end also takes the pages the lanes hold
+(`pages.lane_pages`), for the pool's occupancy.
 
 When nothing runs and nothing waits, the loop sleeps until the next
 request is due. Every phase is wrapped in a `TraceAnnotation`
@@ -32,32 +33,37 @@ from typing import Callable, Dict, List, Optional
 import jax
 import numpy as np
 
-from traffic import Request, pages_for
+import pages
+from traffic import Request
 
 
 class Window:
     """What one window recorded, in seconds after it opened."""
 
-    def __init__(self, reqs: List[Request], seconds: float, pool_pages: int,
-                 page_tokens: int):
+    def __init__(self, reqs: List[Request], seconds: float, pool_pages: int):
         self.reqs = reqs
         self.by_id: Dict[int, Request] = {r.rid: r for r in reqs}
         self.seconds = seconds
         self.pool_pages = pool_pages
-        self.page_tokens = page_tokens
-        self.reserved = 0               # pages of requests handed over
+        self.holds: Dict[int, int] = {}  # rid -> pages reserved for it
         self.chunks: List[dict] = []    # t_end, steps, tokens, admitted, pages
         self.t0 = 0.0                   # perf_counter when the window opened
         self.steps0 = 0                 # engine steps before the window
         self.close_state: Optional[dict] = None  # lane tables at the close
         self.grace_s = 0.0
 
+    @property
+    def reserved(self) -> int:
+        """Pages held for the requests handed over and not finished."""
+        return sum(self.holds.values())
+
 
 def _sync(eng, win: Window, now: Callable[[], float], admitted: int) -> None:
     """Wait for the chunk and attribute its tokens."""
     with jax.profiler.TraceAnnotation("bench.sync"):
         seq, n_out, n_pages = jax.device_get(
-            (eng.state.seq_id, eng.state.n_out, eng.state.n_pages))
+            (eng.state.seq_id, eng.state.n_out,
+             pages.lane_pages(eng)["n_pages"]))
     t = now()
     steps = eng.stats["steps"] - win.steps0
     tokens = 0
@@ -70,7 +76,7 @@ def _sync(eng, win: Window, now: Callable[[], float], admitted: int) -> None:
             r.t_first, r.steps_first, r.n_first = t, steps, n
         r.n_seen = n
         if n >= r.max_new:
-            win.reserved -= pages_for(r, win.page_tokens)
+            del win.holds[r.rid]
         if t <= win.seconds:
             r.t_last_w, r.steps_last_w = t, steps
     win.chunks.append(
@@ -81,9 +87,10 @@ def _sync(eng, win: Window, now: Callable[[], float], admitted: int) -> None:
 
 def lane_tables(eng) -> dict:
     """The lanes' page tables and counts, read from the device."""
-    st = eng.state
+    held = pages.lane_pages(eng)
     shard, off, seq, n_pages = jax.device_get(
-        (st.page_shard, st.page_off, st.seq_id, st.n_pages)
+        (held["page_shard"], held["page_off"], eng.state.seq_id,
+         held["n_pages"])
     )
     return {"page_shard": shard, "page_off": off, "seq_id": seq,
             "n_pages": n_pages, "free_pages": eng.device_free_pages(),
@@ -96,7 +103,7 @@ def serve(eng, reqs: List[Request], seconds: float, chunk: int,
     until every admitted or due request has finished (at most
     `grace_cap_s` more), so that each answer can be checked and every
     page must be back in the pool."""
-    win = Window(reqs, seconds, eng.ecfg.num_pages, eng.ecfg.page_tokens)
+    win = Window(reqs, seconds, eng.ecfg.num_pages)
     pending = list(reqs)  # in due order
     nxt = 0
     win.steps0 = eng.stats["steps"]
@@ -115,12 +122,12 @@ def serve(eng, reqs: List[Request], seconds: float, chunk: int,
                 r = pending[nxt]
                 if r.due > min(t, limit):
                     break
-                need = pages_for(r, win.page_tokens)
+                need = pages.request_pages(eng, len(r.prompt), r.max_new)
                 if win.reserved + need > win.pool_pages:
                     break
                 r.submitted = t
                 eng.submit(_engine_request(r))
-                win.reserved += need
+                win.holds[r.rid] = need
                 nxt += 1
 
     def one_chunk() -> None:

@@ -116,6 +116,68 @@ def test_pool_that_binds_holds_requests_back_and_none_overflows(
     assert max(held) > pages // 2
 
 
+def test_gate_reserves_a_page_for_every_16_tokens_of_prompt_and_output(
+        tiny_run, monkeypatch):
+    """With the pool binding, the gate asks the engine for each request's
+    pages, and every answer, and so every hold and release, is
+    ceil((prompt + max_new) / 16): the gate admits the requests that a
+    fixed formula of a page per 16 tokens would, in the same chunks."""
+    import pages
+    import serve_loop
+
+    asked = []
+    real_ask, real_sync = pages.request_pages, serve_loop._sync
+
+    def ask(eng, prompt_len, max_new):
+        need = real_ask(eng, prompt_len, max_new)
+        asked.append((need, -(-(prompt_len + max_new) // 16)))
+        return need
+
+    def sync(eng, win, now, admitted):
+        real_sync(eng, win, now, admitted)
+        live = [win.by_id[rid] for rid in win.holds]
+        assert win.reserved == sum(-(-(len(r.prompt) + r.max_new) // 16)
+                                   for r in live)
+        assert all(r.n_seen < r.max_new for r in live)
+
+    monkeypatch.setattr(pages, "request_pages", ask)
+    monkeypatch.setattr(serve_loop, "_sync", sync)
+    monkeypatch.setattr(run, "GRACE_CAP_S", 30.0)
+    res = tiny_run(num_pages=64)
+    assert res["correct"] is True
+    assert asked and all(new == old for new, old in asked)
+
+
+def _under_reported_pages(eng):
+    """The engine's accessor under-reports one lane's pages: the lane
+    that holds most loses its newest page from its table and count."""
+    st = eng.state
+    lane = jnp.argmax(st.n_pages)
+    last = jnp.maximum(st.n_pages[lane] - 1, 0)
+    holds = st.n_pages[lane] > 0
+    shard = st.page_shard.at[lane, last].set(
+        jnp.where(holds, -1, st.page_shard[lane, last]))
+    return {"page_shard": shard, "page_off": st.page_off,
+            "n_pages": st.n_pages.at[lane].add(-holds.astype(jnp.int32))}
+
+
+def test_under_reported_lane_pages_are_unaccounted(tiny_run, monkeypatch):
+    import serve_loop
+    from repro.serve.jit_engine import JitServeEngine
+
+    real_serve = serve_loop.serve
+
+    def serve(*a, **kw):
+        monkeypatch.setattr(JitServeEngine, "lane_pages",
+                            _under_reported_pages, raising=False)
+        return real_serve(*a, **kw)
+
+    monkeypatch.setattr(serve_loop, "serve", serve)
+    res = tiny_run()
+    assert res["checks"]["pages_unaccounted"]["value"] >= 1
+    assert res["correct"] is False
+
+
 def _altered_token(real, ecfg, params, state, n):
     """A token altered where it is produced: every lane's newest."""
     state, traj = real(ecfg, params, state, n)

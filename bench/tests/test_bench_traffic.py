@@ -92,14 +92,6 @@ def test_every_cut_names_its_cause_and_caps_the_tail(name):
         assert (grid[kind] == np.minimum(uncut, cut["tokens"])).all()
 
 
-def test_pages_for_counts_the_whole_output():
-    r = traffic.Request(rid=0, due=0.0, prompt=np.zeros(33, np.int32),
-                        max_new=15)
-    assert traffic.pages_for(r, 16) == 3
-    r.max_new = 16
-    assert traffic.pages_for(r, 16) == 4
-
-
 def test_requests_for_covers_the_window():
     mix = _mix("chat-poisson")
     reqs = traffic.requests_for(mix, 1, 10.0, 100, 8.0)

@@ -105,12 +105,6 @@ def generate(
     return out
 
 
-def pages_for(r: Request, page_tokens: int) -> int:
-    """Pages a request holds once its whole output is written: the
-    engine's own bound for a lane (prompt + max_new tokens)."""
-    return -(-(len(r.prompt) + r.max_new) // page_tokens)
-
-
 def requests_for(mix: dict, seed: int, seconds: float, vocab: int,
                  rate_per_s: float) -> List[Request]:
     """Every request due inside a window of `seconds`, and one block
