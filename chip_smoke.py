@@ -18,7 +18,11 @@ d_ff 6912, vocab 50304).  Then, in this one process:
      reference, is what the served step runs);
   4. eight requests (prompts of 16-256 tokens, 32 new tokens each)
      served to completion in fused chunks: every request gets its full
-     budget, none overflows, every page returns to the pool.
+     budget, none overflows, every page returns to the pool;
+  5. the kernel with a sliding window at Mellum2-12B's heads (32 q over
+     4 kv heads of dim 128, window 1024, 160-page tables) against the
+     reference, at contexts on both sides of the window and of a page
+     boundary.
 
 Earlier lines report the device, compile seconds per jitted function,
 the memory figures and the kernel's error.  The last line is
@@ -82,25 +86,32 @@ def check(cond, msg: str) -> None:
         fail(msg)
 
 
-def kernel_error(cfg, rng) -> float:
+def kernel_error(cfg, rng, max_pages=MAX_LANE_PAGES, window=0,
+                 contexts=()) -> float:
     """Max abs error of the paged-attention kernel at the model's widths
     against the reference, on the engine's pool and lane geometry:
     distinct random pages per lane, -1 padded, a context inside the
-    mapped pages, and an idle last lane (context 0) as the engine
-    passes for an inactive lane."""
+    mapped pages (the given `contexts` first, then random ones), and an
+    idle last lane (context 0) as the engine passes for an inactive
+    lane; with `window`, a sliding window layer's."""
     H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     keys = jax.random.split(jax.random.PRNGKey(SEED + 1), 3)
     pool_shape = (NUM_PAGES, PAGE_TOKENS, Hkv, D)
     kp = jax.random.normal(keys[0], pool_shape, jnp.bfloat16)
     vp = jax.random.normal(keys[1], pool_shape, jnp.bfloat16)
     q = jax.random.normal(keys[2], (MAX_BATCH, H, D), jnp.bfloat16)
-    tables = np.full((MAX_BATCH, MAX_LANE_PAGES), -1, np.int32)
+    tables = np.full((MAX_BATCH, max_pages), -1, np.int32)
     ctx = np.zeros((MAX_BATCH,), np.int32)
     for b in range(MAX_BATCH - 1):
-        n = int(rng.integers(1, MAX_LANE_PAGES + 1))
+        if b < len(contexts):
+            ctx[b] = contexts[b]
+            n = -(-ctx[b] // PAGE_TOKENS)
+            tables[b, :n] = rng.choice(NUM_PAGES, size=n, replace=False)
+            continue
+        n = int(rng.integers(1, max_pages + 1))
         tables[b, :n] = rng.choice(NUM_PAGES, size=n, replace=False)
         ctx[b] = int(rng.integers(1, n * PAGE_TOKENS + 1))
-    args = (jnp.asarray(tables), jnp.asarray(ctx))
+    args = (jnp.asarray(tables), jnp.asarray(ctx), window)
     out = ops.paged_attention(q, kp[None], vp[None], 0, *args)
     with jax.default_matmul_precision("highest"):
         ref = paged_attention_reference(q, kp, vp, *args)
@@ -131,7 +142,7 @@ def decode_logits(cfg, params, rng) -> np.ndarray:
     tokens = jnp.asarray(
         rng.integers(0, cfg.vocab_size, size=MAX_BATCH), jnp.int32
     )
-    lg, _ = paged_decode_step(
+    lg, _, _ = paged_decode_step(
         cfg, params, pool, tables, ctx, tokens,
         page_tokens=PAGE_TOKENS, dtype=jnp.bfloat16,
     )
@@ -240,6 +251,18 @@ def main() -> None:
     print(f"device: {dev.platform} {dev.device_kind} "
           f"(count {len(jax.devices())})")
     smoke(get_config("stablelm-3b"))
+    # 5. the window mask at Mellum2-12B's heads
+    mellum = get_config("mellum2-12b")
+    window = mellum.window_pattern[0]
+    err = kernel_error(
+        mellum, np.random.default_rng(SEED + 5), max_pages=160,
+        window=window,
+        contexts=(window - 1, window, window + 1, window + PAGE_TOKENS + 1,
+                  2 * window - 1, 2560),
+    )
+    print(f"windowed kernel ({mellum.name} heads, window {window}) max abs "
+          f"error vs reference: {err!r} (tolerance {KERNEL_ATOL})")
+    check(err <= KERNEL_ATOL, f"windowed kernel error {err} > {KERNEL_ATOL}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices()),

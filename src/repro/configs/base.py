@@ -33,6 +33,22 @@ def lm_shapes() -> Dict[str, ShapeSpec]:
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling with Hugging Face's parameter names
+    (`rope_type` "yarn"); `models.layers.yarn_freqs` computes it."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    # cos and sin are scaled by it; None -> 0.1 ln(factor) + 1
+    attention_factor: Optional[float] = None
+
+
+ROPE_KINDS = ("plain", "yarn")
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str  # dense | moe | hybrid | ssm | vlm | audio
@@ -56,10 +72,21 @@ class ArchConfig:
     # "scatter" (baseline) | "einsum" (GShard one-hot matmul dispatch)
     dispatch_mode: str = "scatter"
     dispatch_group: int = 2048
+    # width of one expert's MLP; 0 = d_ff (d_ff stays the dense width)
+    moe_d_ff: int = 0
+    # the experts this chip holds: [expert_start, expert_start +
+    # experts_held) of the router's n_experts; 0 = all of them
+    expert_start: int = 0
+    experts_held: int = 0
 
     # attention pattern: per-layer sliding window, cycled over layers;
     # 0 = global attention. () = all-global.
     window_pattern: Tuple[int, ...] = ()
+    # rotary kind per layer, cycled over layers like window_pattern:
+    # "plain" (rope_theta) or "yarn" (rope_theta scaled by `yarn`);
+    # () = all plain
+    rope_pattern: Tuple[str, ...] = ()
+    yarn: Optional[YaRN] = None
     attn_softcap: float = 0.0
     final_softcap: float = 0.0
     post_norm: bool = False  # gemma2-style post-block norms
@@ -87,6 +114,20 @@ class ArchConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if not set(self.rope_pattern) <= set(ROPE_KINDS):
+            raise ValueError(f"rope_pattern kinds are {ROPE_KINDS}")
+        if "yarn" in self.rope_pattern and self.yarn is None:
+            raise ValueError("a yarn layer needs `yarn` parameters")
+        if self.expert_start + self.held_experts > self.n_experts:
+            raise ValueError("the held experts lie outside the router's")
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:
@@ -107,7 +148,9 @@ class ArchConfig:
         if self.family in ("dense", "moe", "vlm", "audio"):
             per_layer = attn
             if self.n_experts:
-                per_layer += d * self.n_experts + self.n_experts * 3 * d * ff
+                per_layer += d * self.n_experts + (
+                    self.held_experts * 3 * d * self.expert_d_ff
+                )
             else:
                 per_layer += 3 * d * ff
             total += self.n_layers * per_layer
@@ -125,11 +168,14 @@ class ArchConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Per-token active params (MoE: only top_k experts count)."""
+        """Per-token active params (MoE: only top_k experts count; of a
+        held share, the top_k * held / n_experts a token routes here on
+        average)."""
         if not self.n_experts:
             return self.param_count()
-        d, ff = self.d_model, self.d_ff
-        inactive = (self.n_experts - self.top_k) * 3 * d * ff * self.n_layers
+        d, ff = self.d_model, self.expert_d_ff
+        E, held = self.n_experts, self.held_experts
+        inactive = held * (E - self.top_k) * 3 * d * ff * self.n_layers // E
         return self.param_count() - inactive
 
     def supported_shapes(self) -> Dict[str, ShapeSpec]:
@@ -141,10 +187,16 @@ class ArchConfig:
 
     def reduced(self) -> "ArchConfig":
         """Smoke-test configuration of the same family (CPU-runnable)."""
-        pattern_len = max(len(self.window_pattern), 1)
+        pattern_len = max(len(self.window_pattern), len(self.rope_pattern), 1)
         n_layers = max(2, self.attn_every or 0, pattern_len)
         if self.attn_every:
             n_layers = 2 * self.attn_every
+        # a held share stays a share (fewer experts than the router's,
+        # fewer than its top_k too), so the share path is what runs
+        experts = dict(n_experts=min(self.n_experts, 4))
+        if self.held_experts < self.n_experts:
+            experts = dict(n_experts=8, expert_start=0, experts_held=3,
+                           top_k=min(self.top_k, 4))
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
@@ -155,7 +207,8 @@ class ArchConfig:
             head_dim=16,
             d_ff=128,
             vocab_size=256,
-            n_experts=min(self.n_experts, 4),
+            moe_d_ff=32 if self.moe_d_ff else 0,
+            **experts,
             ssm_head_dim=16 if self.ssm_state else self.ssm_head_dim,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             rwkv_head_dim=16,

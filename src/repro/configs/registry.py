@@ -27,6 +27,7 @@ _MODULES = {
     "llava-next-34b": "llava_next_34b",
     "musicgen-large": "musicgen_large",
     "rwkv6-7b": "rwkv6_7b",
+    "mellum2-12b": "mellum2_12b",
 }
 
 ARCH_NAMES = tuple(_MODULES)

@@ -150,13 +150,16 @@ def paged_attention(
     layer: Array,
     block_tables: Array,
     context_lens: Array,
+    window: Optional[Array] = None,
     *,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
     impl: str = "auto",
 ) -> Array:
     """Decode attention over layer `layer` of the stacked pools
-    k/v_pages: [L,P,page,Hkv,D]."""
+    k/v_pages: [L,P,page,Hkv,D], over the newest `window` positions of
+    each context (0 or None = all of it; None compiles the kernel
+    without the window's scalar work)."""
     impl = _resolve(impl)
     if impl == "reference":
         return kref.paged_attention_reference(
@@ -165,6 +168,7 @@ def paged_attention(
             v_pages[layer],
             block_tables,
             context_lens,
+            0 if window is None else window,
             softcap=softcap,
             scale=scale,
         )
@@ -175,6 +179,7 @@ def paged_attention(
         layer,
         block_tables,
         context_lens,
+        window,
         softcap=softcap,
         scale=scale,
         interpret=(impl == "interpret"),

@@ -23,6 +23,20 @@ DMA at (layer, page).  A sliced one-layer operand would make XLA copy
 that layer's whole pool, every page, before each call.  A caller that
 holds one layer passes `pool[None]` and layer 0.
 
+A model with sliding-window layers passes the layer's window as a
+fourth prefetched scalar (0 = global).  A window layer attends to the
+newest `window` positions of the context, the token itself included
+(Hugging Face's `sliding_window`).  Pages that lie wholly before the
+window are skipped with @pl.when, and their k/v index maps repeat the
+first page inside the window, so the pipeline issues no DMA for them;
+the page that straddles the window's start is masked.  At window 0 the
+DMAs and the results are those of a global layer.  A model without
+windows passes none (`window=None`) and gets the kernel without the
+window's scalar work, which runs at every grid step (every table slot
+of every lane): at window 0 it makes a call 21% slower at minitron-4b's
+heads (16 lanes) and 9% at stablelm-3b's (8 lanes) on one v5e, over
+160-slot tables at contexts of 1,100-1,500 tokens.
+
 The page's K/V are flattened to [page*Hkv, D] (row r = slot r // Hkv,
 kv head r % Hkv) so both products are plain 2-D matmuls over all
 heads at once; a static mask keeps each query head on its own kv head
@@ -66,19 +80,15 @@ def _paged_decode_kernel(
     softcap: Optional[float],
     page: int,
     group: int,
-    # prefetched scalars
-    layer_ref,
-    tables_ref,
-    lens_ref,
-    # tensor refs
-    q_ref,
-    k_ref,
-    v_ref,
-    o_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
+    windowed: bool,
+    # prefetched scalars: layer, (window,) tables, lens
+    *refs,
 ):
+    if windowed:
+        layer_ref, win_ref, tables_ref, lens_ref, *refs = refs
+    else:
+        layer_ref, tables_ref, lens_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
     n_pages = pl.num_programs(1)
@@ -92,6 +102,9 @@ def _paged_decode_kernel(
     ctx = lens_ref[b]
     page_id = tables_ref[b, j]
     live = (page_id >= 0) & (j * page < ctx)
+    if windowed:
+        lo = _window_start(win_ref[0], ctx)
+        live = live & ((j + 1) * page > lo)
 
     @pl.when(live)
     def _compute():
@@ -108,7 +121,10 @@ def _paged_decode_kernel(
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         own = col % hkv == row // group
         pos = j * page + col // hkv
-        s = jnp.where(own & (pos < ctx), s, NEG_INF)
+        keep = own & (pos < ctx)
+        if windowed:
+            keep = keep & (pos >= lo)
+        s = jnp.where(keep, s, NEG_INF)
 
         m_prev = m_scr[...]  # [Hq, 1]
         m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -129,6 +145,12 @@ def _paged_decode_kernel(
         o_ref[0] = (acc_scr[...] / norm).astype(o_ref.dtype)
 
 
+def _window_start(window, ctx):
+    """First position a token attends to: ctx - window for a window
+    layer (window > 0), else 0."""
+    return jnp.where(window > 0, jnp.maximum(ctx - window, 0), 0)
+
+
 @functools.partial(
     jax.jit, static_argnames=("softcap", "scale", "interpret")
 )
@@ -139,13 +161,16 @@ def paged_attention(
     layer: Array,
     block_tables: Array,
     context_lens: Array,
+    window: Optional[Array] = None,
     *,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
     interpret: bool = False,
 ) -> Array:
     """q: [B,Hq,D]; k/v_pages: [L,P,page,Hkv,D]; layer: int32 scalar or
-    [1], the layer of the pools to attend over; tables: [B,max_pages]."""
+    [1], the layer of the pools to attend over; tables: [B,max_pages];
+    window: None (a model without windows), or an int32 scalar or [1],
+    the layer's sliding window (0 = global)."""
     B, Hq, D = q.shape
     _, P, page, Hkv, _ = k_pages.shape
     assert Hq % Hkv == 0
@@ -154,16 +179,28 @@ def paged_attention(
     if scale is None:
         scale = 1.0 / math.sqrt(D)
 
-    kernel = functools.partial(_paged_decode_kernel, scale, softcap, page, group)
+    windowed = window is not None
+    kernel = functools.partial(
+        _paged_decode_kernel, scale, softcap, page, group, windowed
+    )
 
-    def q_map(b, j, layer, tables, lens):
+    def q_map(b, j, *scalars):
         return (b, 0, 0)
 
-    def kv_map(b, j, layer, tables, lens):
+    def kv_map(b, j, *scalars):
+        if windowed:
+            layer, win, tables, lens = scalars
+            # a page before the window repeats the window's first page
+            j = jnp.maximum(j, _window_start(win[0], lens[b]) // page)
+        else:
+            layer, tables, lens = scalars
         return (layer[0], jnp.maximum(tables[b, j], 0), 0, 0, 0)
 
+    scalars = [jnp.reshape(layer, (1,)).astype(jnp.int32)]
+    if windowed:
+        scalars.append(jnp.reshape(window, (1,)).astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars) + 2,
         grid=(B, max_pages),
         in_specs=[
             pl.BlockSpec((1, Hq, D), q_map),
@@ -186,7 +223,7 @@ def paged_attention(
         # profiler trace, where the benchmark finds the kernel by it
         name="paged_attention",
     )(
-        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        *scalars,
         block_tables.astype(jnp.int32),
         context_lens.astype(jnp.int32),
         q,

@@ -74,6 +74,7 @@ def paged_attention_reference(
     v_pages: Array,
     block_tables: Array,
     context_lens: Array,
+    window: Array | int = 0,
     *,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
@@ -84,6 +85,8 @@ def paged_attention_reference(
     k/v_pages:    [P, page, Hkv, D] — global page pool (buddy blocks)
     block_tables: [B, max_pages]    — page ids per sequence, -1 padded
     context_lens: [B]               — valid kv length per sequence
+    window:       []                — sliding window: the newest
+                                      `window` positions (0 = all)
     returns       [B, Hq, D]
     """
     B, Hq, D = q.shape
@@ -111,6 +114,9 @@ def paged_attention_reference(
         B, max_pages * page
     )
     valid &= pos < context_lens[:, None]
+    window = jnp.asarray(window, jnp.int32)
+    lo = jnp.where(window > 0, context_lens - window, 0)
+    valid &= pos >= lo[:, None]
     s = jnp.where(valid[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhl,blhd->bhd", p, vr.astype(jnp.float32))

@@ -187,10 +187,12 @@ def attention_block(
     softcap: Optional[float] = None,
     positions: Optional[Array] = None,
     chunk: int = 1024,
+    rope: Optional[Tuple[Array, Array]] = None,
 ) -> Array:
     """Full GQA block (projections + RoPE + chunked attention).
 
-    x: [B, S, d] -> [B, S, d].
+    x: [B, S, d] -> [B, S, d].  `rope`: the layer's rotary table where
+    the model mixes kinds (`layers.apply_rope`).
     """
     B, S, d = x.shape
     dtype = x.dtype
@@ -199,8 +201,8 @@ def attention_block(
     v = (x @ p["wv"].astype(dtype)).reshape(B, S, n_kv_heads, head_dim)
     if positions is None:
         positions = jnp.arange(S)[None, :]
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    q = apply_rope(q, positions, rope_theta, rope)
+    k = apply_rope(k, positions, rope_theta, rope)
     out = chunked_attention(
         q, k, v, causal=causal, window=window, softcap=softcap, chunk=chunk
     )
@@ -255,6 +257,7 @@ def attention_decode_stacked(
     rope_theta: float,
     window: Optional[Array] = None,
     softcap: Optional[float] = None,
+    rope: Optional[Tuple[Array, Array]] = None,
 ) -> Tuple[Array, Array, Array]:
     """Decode step against a stacked cache [L, B, S, Hkv, D].
 
@@ -270,8 +273,8 @@ def attention_decode_stacked(
     k = (x @ p["wk"].astype(dtype)).reshape(B, 1, n_kv_heads, head_dim)
     v = (x @ p["wv"].astype(dtype)).reshape(B, 1, n_kv_heads, head_dim)
     positions = jnp.full((B, 1), pos, jnp.int32)
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    q = apply_rope(q, positions, rope_theta, rope)
+    k = apply_rope(k, positions, rope_theta, rope)
     zero = jnp.zeros((), jnp.int32)
     k_all = lax.dynamic_update_slice(
         k_all, k[None], (layer, zero, pos, zero, zero)

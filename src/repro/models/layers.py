@@ -7,7 +7,8 @@ jnp arrays); `apply` functions are pure.  Params are created in float32
 
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,13 +40,59 @@ def rope_freqs(head_dim: int, theta: float) -> Array:
     )
 
 
-def apply_rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
-    """x: [..., S, H, D]; positions: broadcastable to [..., S]."""
+def yarn_freqs(head_dim: int, theta: float, yarn) -> Tuple[Array, float]:
+    """YaRN's inverse frequencies and cos/sin scale, as Hugging Face's
+    `_compute_yarn_parameters` defines them (`truncate` on): each
+    frequency blends extrapolation (theta's own) and interpolation
+    (theta's over `factor`) by a linear ramp between the correction
+    dims of `beta_fast` and `beta_slow` at the original context.
+    `yarn` is a `configs.base.YaRN`."""
+    n = head_dim // 2
+    L0 = yarn.original_max_position_embeddings
+
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(L0 / (rotations * 2 * math.pi))) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001  # as HF: no zero-width ramp
+    ramp = jnp.clip(
+        (jnp.arange(n, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0
+    )
+    pos_freqs = theta ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    )
+    extrapolation = 1.0 - ramp  # weight of theta's own frequency
+    inv = (1.0 / (yarn.factor * pos_freqs)) * (1.0 - extrapolation) + (
+        1.0 / pos_freqs
+    ) * extrapolation
+    scale = yarn.attention_factor
+    if scale is None:
+        scale = 0.1 * math.log(yarn.factor) + 1.0 if yarn.factor > 1 else 1.0
+    return inv, float(scale)
+
+
+def apply_rope(
+    x: Array,
+    positions: Array,
+    theta: float = 10000.0,
+    rope: Optional[Tuple[Array, Array]] = None,
+) -> Array:
+    """x: [..., S, H, D]; positions: broadcastable to [..., S].
+
+    `rope`, where given, is (inverse frequencies [D/2], cos/sin scale)
+    of the layer (a YaRN layer's, or a plain one's in a model that
+    mixes kinds) and replaces theta's."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # [D/2]
+    freqs = rope_freqs(d, theta) if rope is None else rope[0]  # [D/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, D/2]
     angles = angles[..., None, :]  # broadcast over heads
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if rope is not None:
+        sin, cos = sin * rope[1], cos * rope[1]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -54,6 +54,8 @@ from repro.models.layers import (
     init_swiglu,
     logits as lm_logits,
     rms_norm,
+    rope_freqs,
+    yarn_freqs,
 )
 from repro.models.sharding import MeshAxes, act_spec, constrain
 
@@ -78,7 +80,10 @@ def _init_dense_layer(cfg: ArchConfig, key: Array) -> dict:
         p["ln1_post"] = init_rms_norm(cfg.d_model)
         p["ln2_post"] = init_rms_norm(cfg.d_model)
     if cfg.n_experts:
-        p["moe"] = moe_lib.init_moe(km, cfg.d_model, cfg.d_ff, cfg.n_experts)
+        p["moe"] = moe_lib.init_moe(
+            km, cfg.d_model, cfg.expert_d_ff, cfg.n_experts,
+            cfg.expert_start, cfg.experts_held,
+        )
     else:
         p["mlp"] = init_swiglu(km, cfg.d_model, cfg.d_ff)
     return p
@@ -141,6 +146,35 @@ def window_array(cfg: ArchConfig) -> Array:
     return jnp.tile(pat, reps)[: cfg.n_layers]
 
 
+def rope_arrays(cfg: ArchConfig) -> Optional[Tuple[Array, Array]]:
+    """Per-layer rotary tables, scanned with the layers: inverse
+    frequencies [L, D/2] and the cos/sin scale [L] of each layer's kind
+    (`rope_pattern`, cycled).  None where the model has one kind, plain
+    rotary at `rope_theta`, which `apply_rope` computes itself."""
+    if not cfg.rope_pattern:
+        return None
+    plain = rope_freqs(cfg.head_dim, cfg.rope_theta)
+    tables = {"plain": (plain, 1.0)}
+    if cfg.yarn is not None:
+        tables["yarn"] = yarn_freqs(cfg.head_dim, cfg.rope_theta, cfg.yarn)
+    kinds = [cfg.rope_pattern[i % len(cfg.rope_pattern)]
+             for i in range(cfg.n_layers)]
+    inv = jnp.stack([tables[k][0] for k in kinds])
+    scale = jnp.asarray([tables[k][1] for k in kinds], jnp.float32)
+    return inv, scale
+
+
+def serve_moe(
+    cfg: ArchConfig, p: dict, h: Array, live: Optional[Array] = None
+) -> Tuple[Array, dict]:
+    """The expert MLP of the serving paths: routed over the held
+    experts, drop-free (`moe.apply_moe_routed`)."""
+    return moe_lib.apply_moe_routed(
+        p, h, top_k=cfg.top_k, expert_start=cfg.expert_start,
+        dtype=h.dtype, live=live,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Forward (training / prefill trunk)
 # ---------------------------------------------------------------------------
@@ -158,10 +192,12 @@ def _attn_kwargs(cfg: ArchConfig) -> dict:
 
 def _dense_body(cfg: ArchConfig, axes, carry, xs):
     x, aux = carry
-    lp, window = xs
+    lp, window, rope = xs
     x = constrain(x, axes, act_spec(axes, "dp", None, None))
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-    h = attention_block(lp["attn"], h, window=window, **_attn_kwargs(cfg))
+    h = attention_block(
+        lp["attn"], h, window=window, rope=rope, **_attn_kwargs(cfg)
+    )
     if cfg.post_norm:
         h = rms_norm(h, lp["ln1_post"], cfg.norm_eps)
     x = x + h
@@ -177,6 +213,7 @@ def _dense_body(cfg: ArchConfig, axes, carry, xs):
             axes=axes,
             dispatch=cfg.dispatch_mode,
             group_size=cfg.dispatch_group,
+            expert_start=cfg.expert_start,
         )
         aux = aux + a
     else:
@@ -226,7 +263,7 @@ def forward(
     aux0 = jnp.zeros((), jnp.float32)
     if cfg.family in ("dense", "moe", "vlm", "audio"):
         body = functools.partial(_dense_body, cfg, axes)
-        xs = (params["layers"], window_array(cfg))
+        xs = (params["layers"], window_array(cfg), rope_arrays(cfg))
     elif cfg.family == "hybrid":
         body = functools.partial(_hybrid_body, cfg, axes, params["shared_attn"])
         xs = params["groups"]
@@ -336,20 +373,25 @@ def decode_step(
         # per step (verified via the HLO roofline walk).
         def body(carry, xs):
             x, k_all, v_all = carry
-            lp, window, li = xs
+            lp, window, li, rope = xs
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             h, k_all, v_all = attention_decode_stacked(
                 lp["attn"], h, k_all, v_all, li, pos,
-                window=window, **_attn_kwargs(cfg),
+                window=window, rope=rope, **_attn_kwargs(cfg),
             )
             if cfg.post_norm:
                 h = rms_norm(h, lp["ln1_post"], cfg.norm_eps)
             x = x + h
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.n_experts:
-                # Decode defaults to drop-free capacity (= n_experts x
-                # the mean load): capacity dropping is a training
-                # trade-off, not acceptable at serving time.
+            if cfg.n_experts and axes is None:
+                h, _ = serve_moe(cfg, lp["moe"], h)
+            elif cfg.n_experts:
+                # Sharded decode keeps the capacity dispatch, whose
+                # expert axis shards on 'model' with an all-to-all (the
+                # routed path has no exchange between chips), at
+                # drop-free capacity (= n_experts x the mean load):
+                # capacity dropping is a training trade-off, not
+                # acceptable at serving time.
                 # Dispatch is always the scatter path at decode: with
                 # T = batch tokens the one-hot matmuls of the einsum
                 # mode cost more than the tiny scatter (measured:
@@ -365,6 +407,7 @@ def decode_step(
                     n_blocks=cfg.dispatch_blocks,
                     axes=axes,
                     dispatch="scatter",
+                    expert_start=cfg.expert_start,
                 )
             else:
                 h = apply_swiglu(lp["mlp"], h, dtype=h.dtype)
@@ -375,7 +418,8 @@ def decode_step(
         (x, k_new, v_new), _ = lax.scan(
             body,
             (x, cache["k"], cache["v"]),
-            (params["layers"], windows, jnp.arange(cfg.n_layers)),
+            (params["layers"], windows, jnp.arange(cfg.n_layers),
+             rope_arrays(cfg)),
         )
         cache = dict(cache, k=k_new, v=v_new)
 
@@ -500,7 +544,7 @@ def prefill(
         windows = window_array(cfg)
 
         def body(x, xs):
-            lp, window = xs
+            lp, window, rope = xs
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             # attention with KV capture for the cache
             Bx, Sx, d = h.shape
@@ -514,8 +558,8 @@ def prefill(
             v = (h @ lp["attn"]["wv"].astype(dt)).reshape(
                 Bx, Sx, cfg.n_kv_heads, cfg.head_dim
             )
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+            q = apply_rope(q, positions, cfg.rope_theta, rope)
+            k = apply_rope(k, positions, cfg.rope_theta, rope)
             o = chunked_attention(
                 q, k, v, causal=True, window=window,
                 softcap=cfg.attn_softcap or None,
@@ -525,8 +569,10 @@ def prefill(
                 h = rms_norm(h, lp["ln1_post"], cfg.norm_eps)
             x = x + h
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.n_experts:
-                # serving path: drop-free by default (see decode_step)
+            if cfg.n_experts and axes is None:
+                h, _ = serve_moe(cfg, lp["moe"], h)
+            elif cfg.n_experts:
+                # sharded prefill: drop-free capacity (see decode_step)
                 h, _ = moe_lib.apply_moe(
                     lp["moe"], h, top_k=cfg.top_k,
                     capacity_factor=(
@@ -535,6 +581,7 @@ def prefill(
                     dtype=h.dtype, n_blocks=cfg.dispatch_blocks, axes=axes,
                     dispatch=cfg.dispatch_mode,
                     group_size=cfg.dispatch_group,
+                    expert_start=cfg.expert_start,
                 )
             else:
                 h = apply_swiglu(lp["mlp"], h, dtype=h.dtype)
@@ -542,7 +589,9 @@ def prefill(
                 h = rms_norm(h, lp["ln2_post"], cfg.norm_eps)
             return x + h, (pad_kv(k), pad_kv(v))
 
-        x, (ks, vs) = lax.scan(body, x, (params["layers"], windows))
+        x, (ks, vs) = lax.scan(
+            body, x, (params["layers"], windows, rope_arrays(cfg))
+        )
         cache = dict(cache, k=ks, v=vs)
 
     elif cfg.family == "hybrid":

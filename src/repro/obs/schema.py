@@ -181,6 +181,12 @@ _SPECS = [
            "pages"),
     _gauge("free_pages_shard", "per-shard free pages (vector gauge)",
            "pages"),
+    _counter("expert_reads",
+             "held experts with at least one routed token, summed over "
+             "the layers of each decode step", "experts"),
+    _counter("expert_tokens",
+             "(token, held expert) pairs the decode steps routed",
+             "pairs"),
     MetricSpec(
         "alloc_rounds_hist",
         "histogram",
@@ -376,6 +382,8 @@ ENGINE_METRICS: Tuple[str, ...] = (
     "probe_distance_hist",
     "ring_events",
     "ring_dropped",
+    "expert_reads",
+    "expert_tokens",
 )
 
 for _name in ENGINE_METRICS:

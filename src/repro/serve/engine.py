@@ -192,7 +192,7 @@ class ServeEngine:
         toks = np.zeros(B2, np.int32)
         toks[:B] = [self.running[i].out_tokens[-1] for i in ids]
         active = np.arange(B2) < B
-        lg, self.pool = paged_decode_step(
+        lg, self.pool, _ = paged_decode_step(
             self.cfg,
             self.params,
             self.pool,

@@ -331,7 +331,7 @@ def _engine_step_impl(
         writable = state.active & ~overflow_now
         tables = global_tables(ecfg, page_shard, page_off)
         pool = {"k": state.kv_k, "v": state.kv_v}
-        logits, pool = paged_decode_step(
+        logits, pool, experts = paged_decode_step(
             ecfg.arch, params, pool, tables, state.ctx, state.last_tok,
             page_tokens=pt, impl=ecfg.impl, dtype=ecfg.jdtype,
             active=writable,
@@ -437,6 +437,8 @@ def _engine_step_impl(
         m["largest_run"] = run
         m["fastpath_hits"] = astats["fastpath_hits"]
         m["fastpath_spills"] = astats["fastpath_spills"]
+        m["expert_reads"] = experts["expert_reads"]
+        m["expert_tokens"] = experts["expert_tokens"]
         # ring counters as per-step deltas (merge sums them back up)
         m["ring_events"] = ring.count - state.ring.count
         m["ring_dropped"] = oring.dropped(ring) - oring.dropped(state.ring)
@@ -754,7 +756,10 @@ class JitServeEngine:
         # the `engine.*` records of `_span`, and the `admit` / `decode`
         # / `drain` records that the trace exporter and the benchmark's
         # `admit_host_ms` read (admissions that admitted, decode chunks,
-        # drains that drained); the newest SPAN_JOURNAL_CAP are held
+        # drains that drained); for an expert model, an `experts`
+        # record at every drain with the running `expert_reads` and
+        # `expert_tokens` of the steps before `step`; the newest
+        # SPAN_JOURNAL_CAP are held
         self.spans = SpanJournal()
         self._open: List[int] = []  # indices of the open `_span` records
         self._t_origin = time.perf_counter()
@@ -909,12 +914,25 @@ class JitServeEngine:
         """Collect retired lanes (one host sync), clear them, and
         return the drained seq ids in retirement-step order."""
         with self._span("drain") as span:
+            # an expert model's running expert counts ride the same read
+            experts = (
+                (self.acc["expert_reads"], self.acc["expert_tokens"])
+                if self.cfg.n_experts else ()
+            )
             with self._span("sync"):
-                seq, act, n_out, out_toks, over, done = jax.device_get((
-                    self.state.seq_id, self.state.active, self.state.n_out,
-                    self.state.out_toks, self.state.overflowed,
-                    self.state.done_step,
-                ))
+                (seq, act, n_out, out_toks, over, done), experts = (
+                    jax.device_get(((
+                        self.state.seq_id, self.state.active,
+                        self.state.n_out, self.state.out_toks,
+                        self.state.overflowed, self.state.done_step,
+                    ), experts)))
+            if experts:
+                self._record_span(
+                    "experts", span["t0"], span["step0"],
+                    step=self.stats["steps"],
+                    expert_reads=int(experts[0]),
+                    expert_tokens=int(experts[1]),
+                )
             lanes = np.nonzero((seq >= 0) & ~act)[0]
             # deterministic retirement order: by retire step, then lane id
             lanes = sorted(lanes, key=lambda i: (int(done[i]), int(i)))

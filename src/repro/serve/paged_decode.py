@@ -9,7 +9,10 @@ produced by `memory.PagedKVManager` (buddy runs).  Each decode step:
   2. scatters them into the pool page/slot given by the block table
      (page = table[b, pos // page_tokens], slot = pos % page_tokens),
   3. attends over the pages via `kernels.ops.paged_attention`
-     (Pallas on TPU, jnp reference elsewhere — same math).
+     (Pallas on TPU, jnp reference elsewhere — same math), a window
+     layer over the newest `window` positions only,
+  4. runs the MLP, or for an expert model the routed experts this
+     chip holds (`models.moe.apply_moe_routed`).
 
 Per-sequence context lengths make this the continuous-batching step:
 sequences at different positions decode together in one jitted call.
@@ -33,10 +36,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.kernels import ops
-from repro.models import moe as moe_lib
 from repro.models.attention import apply_rope
 from repro.models.layers import apply_swiglu, embed, logits as lm_logits, rms_norm
-from repro.models.transformer import prefill, window_array
+from repro.models.transformer import prefill, rope_arrays, serve_moe, window_array
 
 Array = jax.Array
 
@@ -74,8 +76,13 @@ def paged_decode_step(
     impl: str = "auto",
     dtype=jnp.bfloat16,
     active: Array | None = None,  # bool[B]; None = all lanes live
-) -> Tuple[Array, dict]:
-    """Returns (logits [B, V], updated pool). Dense-family archs only."""
+) -> Tuple[Array, dict, dict]:
+    """Returns (logits [B, V], updated pool, expert counts).
+    Dense-family archs only.  The counts are int32 sums over the
+    layers: `expert_reads`, held experts with a routed token, and
+    `expert_tokens`, (token, held expert) pairs routed; zero for a
+    model without experts.  Inactive lanes compute no expert rows and
+    count in neither."""
     assert cfg.family in ("dense", "moe", "vlm", "audio"), cfg.family
     B = tokens.shape[0]
     P = pool["k"].shape[1]
@@ -101,7 +108,7 @@ def paged_decode_step(
         # scan xs/ys, XLA keeps a second whole-pool buffer for the
         # stacked ys, which does not fit beside stablelm-3b on one v5e
         x, pk, pv = carry  # pk/pv: [L, P, page, Hkv, D]
-        lp, window, layer = xs
+        lp, window, layer, rope = xs
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         q = (h @ lp["attn"]["wq"].astype(dtype)).reshape(
             B, 1, cfg.n_heads, cfg.head_dim
@@ -112,8 +119,8 @@ def paged_decode_step(
         v = (h @ lp["attn"]["wv"].astype(dtype)).reshape(
             B, 1, cfg.n_kv_heads, cfg.head_dim
         )
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, rope)
+        k = apply_rope(k, positions, cfg.rope_theta, rope)
         # scatter this token's K/V into its page (inactive lanes were
         # redirected to the OOB page above and are dropped here)
         pk = pk.at[layer, page_idx, slot].set(k[:, 0], mode="drop")
@@ -127,34 +134,35 @@ def paged_decode_step(
             layer,
             block_tables,
             ctx_att,
+            window if cfg.window_pattern else None,
             softcap=cfg.attn_softcap or None,
             impl=impl,
         )
-        # NOTE: sliding-window masking for local layers happens via
-        # context_lens clamping at the engine level (window pages are
-        # the only ones mapped); `window` kept for interface parity.
-        del window
         h = o.reshape(B, 1, -1) @ lp["attn"]["wo"].astype(dtype)
         if cfg.post_norm:
             h = rms_norm(h, lp["ln1_post"], cfg.norm_eps)
         x = x + h
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+        counts = None
         if cfg.n_experts:
-            h, _ = moe_lib.apply_moe(
-                lp["moe"], h, top_k=cfg.top_k,
-                capacity_factor=float(cfg.n_experts), dtype=dtype,
-            )
+            h, counts = serve_moe(cfg, lp["moe"], h, active)
         else:
             h = apply_swiglu(lp["mlp"], h, dtype=dtype)
         if cfg.post_norm:
             h = rms_norm(h, lp["ln2_post"], cfg.norm_eps)
-        return (x + h, pk, pv), None
+        return (x + h, pk, pv), counts
 
     layers = jnp.arange(cfg.n_layers)
-    (x, ks, vs), _ = jax.lax.scan(
-        body, (x, pool["k"], pool["v"]), (params["layers"], windows, layers)
+    (x, ks, vs), counts = jax.lax.scan(
+        body, (x, pool["k"], pool["v"]),
+        (params["layers"], windows, layers, rope_arrays(cfg)),
     )
     h = rms_norm(x, params["final_norm"], cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     lg = lm_logits(h[:, 0], table, cfg.final_softcap or None)
-    return lg, {"k": ks, "v": vs}
+    if counts is None:
+        zero = jnp.zeros((), jnp.int32)
+        counts = {"expert_reads": zero, "expert_tokens": zero}
+    else:
+        counts = {k: v.sum(dtype=jnp.int32) for k, v in counts.items()}
+    return lg, {"k": ks, "v": vs}, counts

@@ -65,7 +65,7 @@ def test_arch_smoke_serve_step(name):
 @pytest.mark.parametrize(
     "name",
     ["stablelm-3b", "gemma2-27b", "zamba2-1.2b", "rwkv6-7b",
-     "phi3.5-moe-42b-a6.6b", "llama4-scout-17b-a16e"],
+     "phi3.5-moe-42b-a6.6b", "llama4-scout-17b-a16e", "mellum2-12b"],
 )
 def test_serve_consistency(name):
     """prefill(S+1) last logits == prefill(S) + decode(token S)."""

@@ -52,9 +52,10 @@ def _spec(x, sharding, float_dtype=None):
 
 
 def _compile_paged_attention(sharding, H, Hkv, D, dtype, P, page=16, B=8,
-                             max_pages=32, L=2):
-    """The kernel on stacked pools of L layers, the layer a traced
-    argument as the decode step's layer scan passes it."""
+                             max_pages=32, L=2, window=False):
+    """The kernel on stacked pools of L layers, the layer (and with
+    `window` the layer's window) a traced argument as the decode step's
+    layer scan passes it."""
     args = [
         jax.ShapeDtypeStruct((B, H, D), dtype, sharding=sharding),
         jax.ShapeDtypeStruct((L, P, page, Hkv, D), dtype, sharding=sharding),
@@ -63,6 +64,8 @@ def _compile_paged_attention(sharding, H, Hkv, D, dtype, P, page=16, B=8,
         jax.ShapeDtypeStruct((B, max_pages), jnp.int32, sharding=sharding),
         jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sharding),
     ]
+    if window:
+        args.append(jax.ShapeDtypeStruct((1,), jnp.int32, sharding=sharding))
     return jax.jit(paged_attention).lower(*args).compile()
 
 
@@ -100,13 +103,25 @@ def test_paged_attention_compiles_at_small_gqa(one_chip, H, Hkv, D, dtype):
     assert KERNEL_CALL in compiled.as_text()
 
 
-def _served_engine(sharding, arch="stablelm-3b", num_pages=512):
+def test_paged_attention_compiles_with_a_window_at_mellum_heads(one_chip):
+    """Mellum2-12B's heads (32 q over 4 kv heads of dim 128) with the
+    layer's window as the fourth prefetched scalar."""
+    cfg = get_config("mellum2-12b")
+    compiled = _compile_paged_attention(
+        one_chip, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+        P=2048, B=32, max_pages=160, window=True,
+    )
+    assert KERNEL_CALL in compiled.as_text()
+
+
+def _served_engine(sharding, arch="stablelm-3b", num_pages=512, n_layers=2,
+                   max_batch=8):
     """The served engine's configuration at an arch's widths (stablelm-3b
     by default), depth cut to 2 layers, with the shapes of its bf16
     weights and state."""
-    cfg = dataclasses.replace(get_config(arch), n_layers=2)
+    cfg = dataclasses.replace(get_config(arch), n_layers=n_layers)
     ecfg = EngineConfig(
-        arch=cfg, num_pages=num_pages, page_tokens=16, max_batch=8,
+        arch=cfg, num_pages=num_pages, page_tokens=16, max_batch=max_batch,
         max_lane_pages=32, max_out=32, dtype="bfloat16", impl="pallas",
     )
     params = jax.tree.map(
@@ -169,3 +184,28 @@ def test_engine_run_reads_the_stacked_pool(one_chip, arch, num_pages):
     for operands in calls:
         k, v = re.findall(r"\w+\[[\d,]*\]", operands)[-2:]
         assert k == v == stacked
+
+
+def test_engine_run_routes_experts_at_mellum_widths(one_chip):
+    """One whole period of Mellum2-12B's layers (three window layers and
+    a full one) at its widths, the held share of 16 of 64 experts, 32
+    lanes: the fused chunk compiles with the kernel, its 32 tokens run
+    every held expert by plain dots that read the layer's weights in
+    place (a copy of one layer's held experts, 66 MB a matrix, would
+    show in the temporaries), and the 2,048-token prefill groups its
+    routed pairs by expert (`ragged-dot`) with no buffer sized by a
+    per-expert capacity (E x T x top_k rows)."""
+    from repro.serve.paged_decode import serve_prefill
+
+    ecfg, params, state = _served_engine(
+        one_chip, "mellum2-12b", num_pages=2048, n_layers=4, max_batch=32)
+    compiled = engine_run.lower(ecfg, params, state, 2).compile()
+    text = compiled.as_text()
+    assert KERNEL_CALL in text and "ragged-dot" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    toks = {"tokens": jax.ShapeDtypeStruct((1, 2048), jnp.int32,
+                                           sharding=one_chip)}
+    text = serve_prefill.lower(ecfg.arch, params, toks, max_len=2048,
+                               dtype=jnp.bfloat16).compile().as_text()
+    assert "ragged-dot" in text
+    assert not re.search(r"bf16\[(16|64),(8192|16385|16384),2304\]", text)
