@@ -19,10 +19,12 @@ d_ff 6912, vocab 50304).  Then, in this one process:
   4. eight requests (prompts of 16-256 tokens, 32 new tokens each)
      served to completion in fused chunks: every request gets its full
      budget, none overflows, every page returns to the pool;
-  5. the kernel with a sliding window at Mellum2-12B's heads (32 q over
-     4 kv heads of dim 128, window 1024, 160-page tables) against the
-     reference, at contexts on both sides of the window and of a page
-     boundary.
+  5. the kernel's live-page walk at minitron-4b's heads (24 q over 8 kv
+     heads of dim 128) and at Mellum2-12B's (32 q over 4 kv heads of
+     dim 128), without a window and, at Mellum2-12B's, with its window
+     of 1024, on 160-page tables, against the reference: contexts up to
+     2,560 on both sides of page, block and window edges, and an idle
+     lane.
 
 Earlier lines report the device, compile seconds per jitted function,
 the memory figures and the kernel's error.  The last line is
@@ -86,7 +88,7 @@ def check(cond, msg: str) -> None:
         fail(msg)
 
 
-def kernel_error(cfg, rng, max_pages=MAX_LANE_PAGES, window=0,
+def kernel_error(cfg, rng, max_pages=MAX_LANE_PAGES, window=None,
                  contexts=()) -> float:
     """Max abs error of the paged-attention kernel at the model's widths
     against the reference, on the engine's pool and lane geometry:
@@ -111,10 +113,10 @@ def kernel_error(cfg, rng, max_pages=MAX_LANE_PAGES, window=0,
         n = int(rng.integers(1, max_pages + 1))
         tables[b, :n] = rng.choice(NUM_PAGES, size=n, replace=False)
         ctx[b] = int(rng.integers(1, n * PAGE_TOKENS + 1))
-    args = (jnp.asarray(tables), jnp.asarray(ctx), window)
-    out = ops.paged_attention(q, kp[None], vp[None], 0, *args)
+    args = (jnp.asarray(tables), jnp.asarray(ctx))
+    out = ops.paged_attention(q, kp[None], vp[None], 0, *args, window)
     with jax.default_matmul_precision("highest"):
-        ref = paged_attention_reference(q, kp, vp, *args)
+        ref = paged_attention_reference(q, kp, vp, *args, window or 0)
     out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
     check(out.shape == (MAX_BATCH, H, D), f"kernel output shape {out.shape}")
     check(np.isfinite(out).all(), "kernel output has non-finite values")
@@ -251,18 +253,21 @@ def main() -> None:
     print(f"device: {dev.platform} {dev.device_kind} "
           f"(count {len(jax.devices())})")
     smoke(get_config("stablelm-3b"))
-    # 5. the window mask at Mellum2-12B's heads
+    # 5. the live-page walk at minitron-4b's and Mellum2-12B's heads
     mellum = get_config("mellum2-12b")
-    window = mellum.window_pattern[0]
-    err = kernel_error(
-        mellum, np.random.default_rng(SEED + 5), max_pages=160,
-        window=window,
-        contexts=(window - 1, window, window + 1, window + PAGE_TOKENS + 1,
-                  2 * window - 1, 2560),
-    )
-    print(f"windowed kernel ({mellum.name} heads, window {window}) max abs "
-          f"error vs reference: {err!r} (tolerance {KERNEL_ATOL})")
-    check(err <= KERNEL_ATOL, f"windowed kernel error {err} > {KERNEL_ATOL}")
+    w = mellum.window_pattern[0]
+    edges = (1, PAGE_TOKENS + 1, 128, 129, 1023, 2047, 2560)
+    for cfg, window, contexts in (
+        (get_config("minitron-4b"), None, edges),
+        (mellum, None, edges),
+        (mellum, w, (w - 1, w, w + 1, w + PAGE_TOKENS + 1, 2 * w - 1,
+                     2 * w + 129, 2560)),
+    ):
+        err = kernel_error(cfg, np.random.default_rng(SEED + 5),
+                           max_pages=160, window=window, contexts=contexts)
+        print(f"kernel ({cfg.name} heads, window {window}) max abs error "
+              f"vs reference: {err!r} (tolerance {KERNEL_ATOL})")
+        check(err <= KERNEL_ATOL, f"kernel error {err} > {KERNEL_ATOL}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices()),

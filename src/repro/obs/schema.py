@@ -187,6 +187,10 @@ _SPECS = [
     _counter("expert_tokens",
              "(token, held expert) pairs the decode steps routed",
              "pairs"),
+    _counter("attn_slots_walked",
+             "page-table slots the paged-attention kernel visits, "
+             "summed over the lanes and layers of each decode step",
+             "slots"),
     MetricSpec(
         "alloc_rounds_hist",
         "histogram",
@@ -384,6 +388,7 @@ ENGINE_METRICS: Tuple[str, ...] = (
     "ring_dropped",
     "expert_reads",
     "expert_tokens",
+    "attn_slots_walked",
 )
 
 for _name in ENGINE_METRICS:

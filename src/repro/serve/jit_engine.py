@@ -331,7 +331,7 @@ def _engine_step_impl(
         writable = state.active & ~overflow_now
         tables = global_tables(ecfg, page_shard, page_off)
         pool = {"k": state.kv_k, "v": state.kv_v}
-        logits, pool, experts = paged_decode_step(
+        logits, pool, counts = paged_decode_step(
             ecfg.arch, params, pool, tables, state.ctx, state.last_tok,
             page_tokens=pt, impl=ecfg.impl, dtype=ecfg.jdtype,
             active=writable,
@@ -437,8 +437,9 @@ def _engine_step_impl(
         m["largest_run"] = run
         m["fastpath_hits"] = astats["fastpath_hits"]
         m["fastpath_spills"] = astats["fastpath_spills"]
-        m["expert_reads"] = experts["expert_reads"]
-        m["expert_tokens"] = experts["expert_tokens"]
+        m["expert_reads"] = counts["expert_reads"]
+        m["expert_tokens"] = counts["expert_tokens"]
+        m["attn_slots_walked"] = counts["attn_slots_walked"]
         # ring counters as per-step deltas (merge sums them back up)
         m["ring_events"] = ring.count - state.ring.count
         m["ring_dropped"] = oring.dropped(ring) - oring.dropped(state.ring)

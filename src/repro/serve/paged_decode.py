@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.kernels import ops
+from repro.kernels.paged_attention import slots_walked
 from repro.models.attention import apply_rope
 from repro.models.layers import apply_swiglu, embed, logits as lm_logits, rms_norm
 from repro.models.transformer import prefill, rope_arrays, serve_moe, window_array
@@ -77,12 +78,14 @@ def paged_decode_step(
     dtype=jnp.bfloat16,
     active: Array | None = None,  # bool[B]; None = all lanes live
 ) -> Tuple[Array, dict, dict]:
-    """Returns (logits [B, V], updated pool, expert counts).
+    """Returns (logits [B, V], updated pool, counts).
     Dense-family archs only.  The counts are int32 sums over the
     layers: `expert_reads`, held experts with a routed token, and
-    `expert_tokens`, (token, held expert) pairs routed; zero for a
-    model without experts.  Inactive lanes compute no expert rows and
-    count in neither."""
+    `expert_tokens`, (token, held expert) pairs routed, zero for a
+    model without experts (inactive lanes compute no expert rows and
+    count in neither); and `attn_slots_walked`, the table slots the
+    paged-attention kernel visits over all lanes, by the bounds it
+    walks (`kernels.paged_attention.slots_walked`)."""
     assert cfg.family in ("dense", "moe", "vlm", "audio"), cfg.family
     B = tokens.shape[0]
     P = pool["k"].shape[1]
@@ -165,4 +168,9 @@ def paged_decode_step(
         counts = {"expert_reads": zero, "expert_tokens": zero}
     else:
         counts = {k: v.sum(dtype=jnp.int32) for k, v in counts.items()}
+    counts["attn_slots_walked"] = slots_walked(
+        ctx_att[None, :], windows[:, None], page=page_tokens,
+        max_pages=block_tables.shape[1], n_kv_heads=cfg.n_kv_heads,
+        head_dim=cfg.head_dim, itemsize=pool["k"].dtype.itemsize,
+    ).sum(dtype=jnp.int32)
     return lg, {"k": ks, "v": vs}, counts

@@ -1,6 +1,9 @@
 """Pallas kernel validation: interpret-mode execution vs pure-jnp
 oracles across shape/dtype sweeps (per-kernel allclose)."""
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,8 +29,14 @@ from repro.kernels.ops import (
     nbbs_wavefront_step,
     paged_attention,
 )
+from repro.kernels import paged_attention as pa
 from repro.kernels.paged_attention import paged_attention as paged_pallas
 from repro.kernels.ref import mha_reference, paged_attention_reference
+from repro.configs import get_config
+from repro.models import init_params
+from repro.serve.engine import Request
+from repro.serve.jit_engine import JitServeEngine
+from repro.serve.paged_decode import init_pool, paged_decode_step
 
 KEY = jax.random.PRNGKey(0)
 
@@ -192,6 +201,140 @@ class TestPagedAttention:
         for layer in range(L):
             _check_paged(outs[layer], q, kp, vp, layer, bt, cl, 2e-5,
                          kernel=impl != "reference")
+
+
+# Cases of the live-page walk (head dim 128), each a batch of lanes
+# on 4-token pages, where N = 16 pages (64 tokens) a block: contexts,
+# and the table slots set to -1 as (lane, slot).  ctx 0 is an idle
+# lane.
+WALK_PAGE, WALK_MAXP = 4, 56
+WALK_CASES = {
+    # mid-page, on a page edge, on a block edge, on two blocks' edge
+    "edges": ((131, 20, 64, 128, 0, 1), ()),
+    # a page missing inside the context, and one past it
+    "holes": ((150, 90), ((0, 10), (0, 45), (1, 0))),
+    # very different lengths, idle lanes between: the next lane's first
+    # block is fetched across lanes with no context
+    "ragged": ((1, 0, 191, 0, 0, 2, 129, 7), ()),
+    "one_lane": ((77,), ()),
+}
+
+
+def _walk_case(seed, ctx, holes, Hq, Hkv, L=2, D=128):
+    """Stacked f32 pools, tables of distinct pages that cover each
+    context and map a few slots past it, the given slots at -1."""
+    B = len(ctx)
+    P = B * WALK_MAXP
+    key = jax.random.fold_in(KEY, seed)
+    shape = (L, P, WALK_PAGE, Hkv, D)
+    kp = rand(jax.random.fold_in(key, 0), shape, jnp.float32)
+    vp = rand(jax.random.fold_in(key, 1), shape, jnp.float32)
+    q = rand(jax.random.fold_in(key, 2), (B, Hq, D), jnp.float32)
+    rng = np.random.default_rng(seed)
+    bt = np.full((B, WALK_MAXP), -1, np.int32)
+    for b, c in enumerate(ctx):
+        n = min(-(-c // WALK_PAGE) + 3, WALK_MAXP)
+        bt[b, :n] = rng.choice(P, size=n, replace=False)
+    for b, j in holes:
+        bt[b, j] = -1
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(ctx, jnp.int32)
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+@pytest.mark.parametrize("window", [None, 6, 70, 1000])
+@pytest.mark.parametrize("Hq,Hkv", [(4, 1), (8, 4), (24, 8)])
+def test_live_page_walk_matches_the_reference(case, window, Hq, Hkv):
+    """The live-page walk (head dim 128) against the reference at both
+    layers of a stacked pool: contexts that end mid-page, on a page edge
+    and on a block edge, a table id of -1 inside and past the context,
+    idle lanes (zeros) between lanes of very different lengths, one
+    lane; windows whose first position falls mid-page (6, 70), inside
+    a block of 16 pages (70) and before position 0 (1000, and 70 for
+    the short lanes)."""
+    ctx, holes = WALK_CASES[case]
+    q, kp, vp, bt, cl = _walk_case(30, ctx, holes, Hq, Hkv)
+    assert pa.pages_per_block(WALK_PAGE, Hkv, 128, 4) == 16
+    for layer in range(kp.shape[0]):
+        out = paged_pallas(q, kp, vp, layer, bt, cl, window, interpret=True)
+        ref = paged_attention_reference(
+            q, kp[layer], vp[layer], bt, cl, 0 if window is None else window)
+        out = np.asarray(out)
+        live = np.asarray(cl) > 0
+        np.testing.assert_allclose(out[live], np.asarray(ref)[live],
+                                   atol=2e-5, rtol=2e-5)
+        assert not out[~live].any()
+
+
+def _grid_of(D):
+    """The grid of the kernel's pallas_call at head dim D."""
+    B, maxp, page, Hkv = 2, 8, 4, 2
+    args = (jnp.zeros((B, 4, D)), jnp.zeros((1, 16, page, Hkv, D)),
+            jnp.zeros((1, 16, page, Hkv, D)), 0,
+            jnp.zeros((B, maxp), jnp.int32), jnp.zeros((B,), jnp.int32))
+    text = str(jax.make_jaxpr(paged_pallas)(*args))
+    return re.findall(r"grid=(\([\d, ]*\))", text)
+
+
+def test_head_dim_picks_the_walk():
+    """A head dim of 128 takes the live-page walk, one grid step a lane;
+    a head dim of 80 (stablelm-3b's) keeps the slot grid, one grid step
+    a table slot, which Mosaic can compile there."""
+    assert not pa.walks_live_pages(2, 80, 4)
+    assert pa.walks_live_pages(2, 128, 4)
+    assert _grid_of(128) == ["(2,)"]
+    assert _grid_of(80) == ["(2, 8)"]
+
+
+@pytest.mark.parametrize("arch,head_dim", [
+    ("mellum2-12b", 128),  # window layers, the live-page walk
+    ("stablelm-3b", 80),  # the slot grid
+])
+def test_decode_step_counts_the_slots_the_kernel_walks(arch, head_dim):
+    """`attn_slots_walked` of one decode step: under the live-page walk
+    ceil((hi - lo) / N) * N for each live lane and layer (hi the
+    context's pages, the new token's included; lo the window's first
+    page, 0 in a global layer), nothing for an idle lane; under the
+    slot grid max_pages for every lane and layer."""
+    cfg = dataclasses.replace(get_config(arch).reduced(), head_dim=head_dim)
+    B, page, maxp = 4, 4, 40
+    ctx = np.asarray([0, 5, 63, 150])
+    active = ctx > 0
+    tables = jnp.arange(B * maxp, dtype=jnp.int32).reshape(B, maxp)
+    _, _, counts = paged_decode_step(
+        cfg, init_params(cfg, KEY), init_pool(cfg, B * maxp, page, jnp.float32),
+        tables, jnp.asarray(ctx, jnp.int32), jnp.zeros((B,), jnp.int32),
+        page_tokens=page, impl="reference", dtype=jnp.float32,
+        active=jnp.asarray(active),
+    )
+    if head_dim % 128:
+        want = maxp * B * cfg.n_layers
+    else:
+        n = pa.pages_per_block(page, cfg.n_kv_heads, head_dim, 4)
+        windows = [cfg.window_pattern[i % len(cfg.window_pattern)]
+                   for i in range(cfg.n_layers)]
+        assert 0 in windows and any(windows)
+        want = 0
+        for w in windows:
+            for c in ctx[active] + 1:
+                hi = -(-c // page)
+                lo = max(c - w, 0) // page if w else 0
+                want += -(-(hi - lo) // n) * n
+    assert int(counts["attn_slots_walked"]) == want
+
+
+def test_engine_totals_count_the_slots_walked():
+    """The engine accumulates the step's `attn_slots_walked` into the
+    totals `stat_totals()` returns: under the slot grid (head dim 16)
+    every slot of every lane's table, at every layer of every step."""
+    cfg = get_config("stablelm-3b").reduced()
+    eng = JitServeEngine(cfg, init_params(cfg, KEY), num_pages=16,
+                         page_tokens=4, max_batch=2, max_lane_pages=8,
+                         max_out=8, dtype=jnp.float32, impl="reference")
+    eng.submit(Request(0, np.arange(5, dtype=np.int32), 4))
+    eng.run_to_completion(chunk=4)
+    tot = eng.stat_totals()
+    assert tot["steps"] > 0
+    assert tot["attn_slots_walked"] == tot["steps"] * 2 * 8 * cfg.n_layers
 
 
 class TestNBBSKernel:

@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.kernels import paged_attention as pa
 from repro.kernels.paged_attention import paged_attention
 from repro.models import init_params
 from repro.serve.jit_engine import (
@@ -110,6 +111,26 @@ def test_paged_attention_compiles_with_a_window_at_mellum_heads(one_chip):
     compiled = _compile_paged_attention(
         one_chip, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
         P=2048, B=32, max_pages=160, window=True,
+    )
+    assert KERNEL_CALL in compiled.as_text()
+
+
+@pytest.mark.parametrize("arch,lanes,num_pages", [
+    ("minitron-4b", 16, 1024),
+    ("mellum2-12b", 32, 2048),  # with its window
+])
+def test_live_page_walk_compiles_at_the_cells_shapes(one_chip, arch, lanes,
+                                                     num_pages):
+    """The live-page walk at a benchmark cell's real shapes: its lanes,
+    its pool over all its layers, 160-slot tables. Mosaic accepts the
+    double-buffered VMEM blocks, the DMA semaphores and the page DMAs
+    out of the stacked HBM pools."""
+    cfg = get_config(arch)
+    assert pa.walks_live_pages(cfg.n_kv_heads, cfg.head_dim, 2)
+    compiled = _compile_paged_attention(
+        one_chip, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16,
+        P=num_pages, B=lanes, max_pages=160, L=cfg.n_layers,
+        window=bool(cfg.window_pattern),
     )
     assert KERNEL_CALL in compiled.as_text()
 
